@@ -8,14 +8,13 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import circulant as cc
-from repro.roofline.analysis import xla_cost_analysis
 
 from .common import emit
 
 
 def compiled_flops(fn, *args) -> float:
     compiled = jax.jit(fn).lower(*args).compile()
-    return float(xla_cost_analysis(compiled)["flops"])  # loud if XLA omits it
+    return float(compiled.cost_analysis()["flops"])  # loud if XLA omits it
 
 
 def main():

@@ -34,7 +34,6 @@ import numpy as np
 
 from repro.kernels import ops as kops
 from repro.layers.attention import chunked_attention
-from repro.roofline.analysis import xla_cost_analysis
 
 
 def make_case(*, slots, max_seq, page, Hkv, G, D, live_len, seed=0):
@@ -79,7 +78,7 @@ def bench_impl(impl: str, args_dev, iters: int) -> dict:
     fn = jax.jit(step_fn(impl))
     compiled = fn.lower(*args_dev).compile()
     mem = compiled.memory_analysis()
-    ca = xla_cost_analysis(compiled)     # list-vs-dict normalized (PR 1)
+    ca = compiled.cost_analysis()
     jax.block_until_ready(fn(*args_dev))          # warm
     best = float("inf")
     for _ in range(iters):

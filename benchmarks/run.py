@@ -13,6 +13,8 @@ import os
 import sys
 import time
 
+from repro.launch.cache import enable_compile_cache
+
 from . import (bench_accuracy_tradeoff, bench_complexity, bench_compression,
                bench_decoupling, bench_equiv_ops, bench_fleet,
                bench_paged_attention, bench_quant, bench_serving,
@@ -49,6 +51,7 @@ ALL = {
 
 def main():
     names = sys.argv[1:] or list(ALL)
+    enable_compile_cache()
     for name in names:
         t0 = time.time()
         ALL[name]()

@@ -15,7 +15,9 @@ Health state machine::
     any ──exception in step / injected crash──▶ DOWN             (crashed)
 
 Signals: dispatch heartbeats (wall time of each ``step`` call — a hang
-fault or a wedged device program shows up as a step timeout),
+fault or a wedged device program shows up as a step timeout; a step in
+which the engine compiled a new device program is slow, not hung, and is
+not timed),
 ``engine.anomalies`` (NaN/Inf-guard trips), SLO watchdog alerts (the
 ``slo.alerts`` counter an ``obs.slo.SloWatchdog`` bound to this
 replica's registry bumps — sustained quality burn degrades the replica
@@ -161,6 +163,7 @@ class EngineReplica:
         if self.faults is not None and self.faults.maybe_crash():
             self._crash("injected crash")
             return False
+        compiled = getattr(self.engine, "programs_compiled", 0)
         t0 = self.clock()
         hang = (self.faults.hang_delay() if self.faults is not None else 0.0)
         if hang > 0.0:
@@ -178,7 +181,8 @@ class EngineReplica:
         slo_alerts = self._slo_alerts()
         slo_delta = slo_alerts - self._last_slo_alerts
         self._last_slo_alerts = slo_alerts
-        timed_out = (t1 - t0) > self.step_timeout_s
+        timed_out = ((t1 - t0) > self.step_timeout_s and
+                     getattr(self.engine, "programs_compiled", 0) == compiled)
         if timed_out:
             self._c_timeouts.inc()
             self.consecutive_timeouts += 1

@@ -76,10 +76,10 @@ def bc_linear_fused_kernel(x: jax.Array, w: jax.Array, n_out: int,
     """Drop-in for bc_matmul_spectral using the fused kernel.
 
     x: (..., n_in); w: (p, q, k) first-row generators.  Call through
-    ``kernels.ops.bc_linear_fused`` — the REPRO_KERNELS dispatch policy
-    ('interpret'/'tpu'/'off') lives there, like the other two kernels;
-    direct callers must pass ``interpret`` explicitly (compiled Pallas is
-    the default, matching a real TPU target)."""
+    ``kernels.ops.bc_linear_fused`` — the platform dispatch ('tpu'/'off',
+    'interpret' on request) lives there, like the other kernels; direct
+    callers must pass ``interpret`` explicitly (compiled Pallas is the
+    default, matching a real TPU target)."""
     p, q, k = w.shape
     lead = x.shape[:-1]
     xb = cc._blockify(x, q, k).reshape(-1, q, k).astype(jnp.float32)

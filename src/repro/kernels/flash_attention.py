@@ -74,7 +74,7 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
 
 def flash_attention(q, k, v, *, causal=True, window=0, softcap=0.0,
                     scale=None, kv_offset=0, block_q=128, block_k=128,
-                    interpret=True):
+                    interpret=False):
     """q: (B, Hq, Sq, D); k/v: (B, Hkv, Skv, D) -> (B, Hq, Sq, D)."""
     B, Hq, Sq, D = q.shape
     _, Hkv, Skv, _ = k.shape

@@ -1,20 +1,17 @@
-"""jit'd public wrappers around the Pallas kernels with XLA fallbacks.
+"""jit'd public wrappers around the Pallas kernels with XLA lowerings.
 
-Kernel dispatch policy (``REPRO_KERNELS`` env var or explicit argument):
-  'interpret' — run the Pallas kernel bodies in interpret mode (CPU-correct;
-                what tests use to validate the TPU kernels).
-  'tpu'       — compiled Pallas (real TPU target).
-  'off'       — pure-XLA lowering (what the 512-device dry-run uses: the
-                einsum/chunked-scan forms lower to the same collectives and
-                FLOPs the roofline needs, without paying interpret-mode cost).
+Each wrapper picks its lowering from the platform (``kernel_mode``):
+  'tpu'       — compiled Pallas, on a TPU backend.
+  'off'       — the pure-XLA lowering, on every other backend (the CPU, and
+                the 512-device dry-run: the einsum/chunked-scan forms lower
+                to the same collectives and FLOPs the roofline needs).
+  'interpret' — the Pallas kernel bodies in interpret mode; only when a
+                caller passes ``mode="interpret"`` (how the CPU tests check
+                the TPU kernels against the XLA lowerings).
 """
 from __future__ import annotations
 
-import functools
-import os
-
 import jax
-import jax.numpy as jnp
 
 from ..core import circulant as _cc
 from . import bc_fused as _bcf
@@ -26,7 +23,8 @@ from . import spectral_matmul as _sm
 
 
 def kernel_mode() -> str:
-    return os.environ.get("REPRO_KERNELS", "off")
+    """Compiled Pallas on a TPU backend, the XLA lowering elsewhere."""
+    return "tpu" if jax.default_backend() == "tpu" else "off"
 
 
 # ---------------------------------------------------------------------------

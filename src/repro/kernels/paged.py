@@ -12,9 +12,9 @@ This mirrors the paper's hierarchical control: the block table is the
 (weights-sized, streamed) — the same split the FPGA controller uses between
 its instruction BRAM and the data buffers.
 
-Call through ``kernels.ops.paged_gather`` — the REPRO_KERNELS dispatch
-('interpret'/'tpu'/'off') lives there; 'off' lowers the same gather as
-plain XLA ``pool[table]`` indexing (see ops).
+Call through ``kernels.ops.paged_gather`` — the platform dispatch
+('tpu'/'off', 'interpret' on request) lives there; 'off' lowers the same
+gather as plain XLA ``pool[table]`` indexing (see ops).
 
 LEGACY / ORACLE PATH: the decode hot loop now streams pages through the
 fused paged flash-decode (``kernels/paged_attention.py``) and never forms

@@ -23,8 +23,8 @@ Two lowerings, dispatched by ``kernels.ops.paged_attention``:
 * ``paged_attention_stream`` — pure XLA: a live-length-bounded
   ``lax.while_loop`` over page-sized KV chunks (one tiny per-chunk gather
   each step; serving-only — a while loop is not reverse-differentiable).
-  Same memory win under XLA alone; this is what ``REPRO_KERNELS=off`` (the
-  default, and the 512-chip dry-run) lowers.
+  Same memory win under XLA alone; this is what every non-TPU backend
+  (the CPU, and the 512-chip dry-run) lowers.
 * ``paged_attention_kernel`` — Pallas: the block table and per-slot
   positions ride scalar prefetch (``PrefetchScalarGridSpec``), so each
   grid step DMAs exactly one pool page straight into VMEM next to the
@@ -124,16 +124,16 @@ def paged_attention_stream(q, pool_k, pool_v, table, positions, *,
 
 
 # ---------------------------------------------------------------------------
-# Pallas kernel ('interpret' / 'tpu' dispatch)
+# Pallas kernel (compiled on TPU; interpret mode when a caller asks)
 # ---------------------------------------------------------------------------
 def _pa_kernel(table_ref, pos_ref, q_ref, k_ref, v_ref, *refs,
-               scale, softcap, page, maxp, quantized):
-    if quantized:                                # int8 lane: per-(page, head)
-        ks_ref, vs_ref, o_ref, m_scr, l_scr, acc_scr = refs   # scales ride
-    else:                                        # tiny (1, 1) VMEM blocks
+               scale, softcap, page, maxp, hkv, quantized):
+    if quantized:                                # int8 lane: (1, 1, Hkv)
+        ks_ref, vs_ref, o_ref, m_scr, l_scr, acc_scr = refs   # page scales
+    else:
         o_ref, m_scr, l_scr, acc_scr = refs
     b = pl.program_id(0)
-    jp = pl.program_id(2)                        # sequential page dim
+    jp = pl.program_id(1)                        # sequential page dim
 
     @pl.when(jp == 0)
     def _init():
@@ -143,92 +143,118 @@ def _pa_kernel(table_ref, pos_ref, q_ref, k_ref, v_ref, *refs,
 
     # Pages past the slot's live extent are fully masked and contribute
     # nothing to the carry — skip their softmax update entirely (the grid
-    # itself is static at maxp: dead table entries all index the single
-    # trash page, so their DMA re-reads one hot page, not the pool).
+    # is static at maxp; their index map repeats the last live page, so
+    # the pipeline issues no DMA for them either).
     @pl.when(jp * page <= pos_ref[b])
     def _update():
-        q = q_ref[0, 0].astype(jnp.float32) * scale            # (G, D)
-        k = k_ref[0, :, 0].astype(jnp.float32)                 # (page, D)
-        v = v_ref[0, :, 0].astype(jnp.float32)
-        if quantized:                            # dequantize in VMEM, right
-            k = k * ks_ref[0, 0]                 # next to the m/l/acc carry
-            v = v * vs_ref[0, 0]
-        s = jnp.dot(q, k.T, preferred_element_type=jnp.float32)  # (G, page)
+        # One grid step holds one page with ALL its KV heads.  Rows are the
+        # Hq query heads, columns the page's (position, kv head) pairs in
+        # pool order, c = position * Hkv + head: both matmuls stay 2-D and
+        # tile-aligned, and a query head only keeps the columns of its own
+        # kv head (the other heads' columns are masked like dead positions).
+        q = q_ref[0].astype(jnp.float32) * scale                # (Hq, D)
+        k = k_ref[0].astype(jnp.float32)                        # (page,Hkv,D)
+        v = v_ref[0].astype(jnp.float32)
+        k = k.reshape(page * hkv, k.shape[-1])
+        v = v.reshape(page * hkv, v.shape[-1])
+        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32)
+        group = s.shape[0] // hkv                # query heads per kv head
+        rows = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+        cols = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        head = rows // group                     # kv head of each query row
+        if quantized:
+            # a kept column's kv head IS the row's head, so the per-(page,
+            # head) scale is a per-row factor: dequantize the scores and the
+            # value contraction instead of the int8 page
+            hshape = (s.shape[0], hkv)
+            onehot = (jax.lax.broadcasted_iota(jnp.int32, hshape, 0) // group
+                      == jax.lax.broadcasted_iota(jnp.int32, hshape, 1))
+            k_row = jnp.sum(jnp.where(onehot, ks_ref[0], 0.0), axis=1,
+                            keepdims=True)               # (Hq, 1)
+            v_row = jnp.sum(jnp.where(onehot, vs_ref[0], 0.0), axis=1,
+                            keepdims=True)
+            s = s * k_row
         if softcap:
             s = softcap * jnp.tanh(s / softcap)
-
-        cols = jp * page + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        mask = cols <= pos_ref[b]                # pos -1 masks everything
+        mask = ((cols % hkv == head)
+                & (jp * page + cols // hkv <= pos_ref[b]))   # pos -1: none
         s = jnp.where(mask, s, _NEG)
 
-        m_prev = m_scr[:, 0]
-        m_new = jnp.maximum(m_prev, s.max(axis=-1))
-        p = jnp.exp(s - m_new[:, None])
+        m_prev = m_scr[...]
+        m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
+        p = jnp.exp(s - m_new)
         p = jnp.where(mask, p, 0.0)
         alpha = jnp.exp(m_prev - m_new)
-        l_new = l_scr[:, 0] * alpha + p.sum(axis=-1)
-        acc_scr[...] = acc_scr[...] * alpha[:, None] + jnp.dot(
-            p, v, preferred_element_type=jnp.float32)
-        m_scr[:, 0] = m_new
-        l_scr[:, 0] = l_new
+        pv = jnp.dot(p, v, preferred_element_type=jnp.float32)  # (Hq, D)
+        if quantized:
+            pv = pv * v_row
+        l_scr[...] = l_scr[...] * alpha + p.sum(axis=-1, keepdims=True)
+        acc_scr[...] = acc_scr[...] * alpha + pv
+        m_scr[...] = m_new
 
     @pl.when(jp == maxp - 1)
     def _finalize():
-        l = jnp.maximum(l_scr[:, 0], 1e-30)
-        o_ref[0, 0] = (acc_scr[...] / l[:, None]).astype(o_ref.dtype)
+        l = jnp.maximum(l_scr[...], 1e-30)
+        o_ref[0] = (acc_scr[...] / l).astype(o_ref.dtype)
 
 
 def paged_attention_kernel(q, pool_k, pool_v, table, positions, *,
                            scale=None, softcap: float = 0.0,
                            interpret: bool = False,
                            k_scale=None, v_scale=None) -> jax.Array:
-    """Same contract as ``paged_attention_stream``; grid (B, Hkv, maxp) with
-    the page dim sequential, block table + positions scalar-prefetched so
-    the page id is known before each step's pool DMA issues.  With
-    ``k_scale``/``v_scale`` ((P, Hkv) f32) the pool is int8: each step's
-    page DMA moves int8 bytes and the (1, 1) scale block for that
-    (page, head) rides along, dequantizing in VMEM."""
+    """Same contract as ``paged_attention_stream``; grid (B, maxp) with the
+    page dim sequential, block table + positions scalar-prefetched so the
+    page id is known before each step's pool DMA issues.  Each step moves
+    one whole page, ``(1, page, Hkv, D)``: its last two dims are the pool's
+    own, which is what the TPU's block-shape rule asks for in every dtype.
+    With ``k_scale``/``v_scale`` ((P, Hkv) f32) the pool is int8: the page
+    DMA moves int8 bytes, and the page's ``(1, 1, Hkv)`` scale row rides
+    along and dequantizes in VMEM."""
     _, page, Hkv, D = pool_k.shape
     B, maxp = table.shape
     Hq = q.shape[1]
-    G = Hq // Hkv
     scale = scale if scale is not None else D ** -0.5
-    qh = q.reshape(B, Hkv, G, D)
     quantized = k_scale is not None
 
-    pool_spec = pl.BlockSpec((1, page, 1, D),
-                             lambda b, h, jp, tref, pref: (tref[b, jp], 0, h, 0))
-    in_specs = [
-        pl.BlockSpec((1, 1, G, D),
-                     lambda b, h, jp, tref, pref: (b, h, 0, 0)),
-        pool_spec,
-        pool_spec,
-    ]
-    operands = [qh, pool_k, pool_v]
+    def page_of(b, jp, tref, pref):
+        # dead steps repeat the slot's last live page (no new DMA)
+        last = jnp.maximum(pref[b], 0) // page
+        return tref[b, jnp.minimum(jp, last)]
+
+    pool_spec = pl.BlockSpec(
+        (1, page, Hkv, D),
+        lambda b, jp, tref, pref: (page_of(b, jp, tref, pref), 0, 0, 0))
+    row_spec = pl.BlockSpec((1, Hq, D),
+                            lambda b, jp, tref, pref: (b, 0, 0))
+    in_specs = [row_spec, pool_spec, pool_spec]
+    operands = [q, pool_k, pool_v]
     if quantized:
         scale_spec = pl.BlockSpec(
-            (1, 1), lambda b, h, jp, tref, pref: (tref[b, jp], h))
+            (1, 1, Hkv),
+            lambda b, jp, tref, pref: (page_of(b, jp, tref, pref), 0, 0))
         in_specs += [scale_spec, scale_spec]
-        operands += [k_scale, v_scale]
+        operands += [k_scale[:, None, :], v_scale[:, None, :]]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,                   # (table, positions)
-        grid=(B, Hkv, maxp),
+        grid=(B, maxp),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, G, D),
-                               lambda b, h, jp, tref, pref: (b, h, 0, 0)),
+        out_specs=row_spec,
         scratch_shapes=[
-            pltpu.VMEM((G, 1), jnp.float32),     # running max
-            pltpu.VMEM((G, 1), jnp.float32),     # running sum
-            pltpu.VMEM((G, D), jnp.float32),     # output accumulator
+            pltpu.VMEM((Hq, 1), jnp.float32),    # running max
+            pltpu.VMEM((Hq, 1), jnp.float32),    # running sum
+            pltpu.VMEM((Hq, D), jnp.float32),    # output accumulator
         ],
     )
     kern = functools.partial(_pa_kernel, scale=scale, softcap=softcap,
-                             page=page, maxp=maxp, quantized=quantized)
-    out = pl.pallas_call(
+                             page=page, maxp=maxp, hkv=Hkv,
+                             quantized=quantized)
+    return pl.pallas_call(
         kern,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, Hkv, G, D), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, Hq, D), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(table, positions, *operands)
-    return out.reshape(B, Hq, D)

@@ -40,7 +40,7 @@ def _kernel(xr_ref, xi_ref, wr_ref, ws1_ref, ws2_ref, yr_ref, yi_ref):
 
 
 def spectral_matmul(xr, xi, wr, ws1, ws2, *, block_b: int = 128,
-                    block_p: int = 128, interpret: bool = True):
+                    block_p: int = 128, interpret: bool = False):
     """Y = X·W in the frequency domain, real planes.
 
     xr/xi: (F, B, Q);  wr/ws1/ws2: (F, Q, P)  ->  yr/yi: (F, B, P)

@@ -152,9 +152,7 @@ def lower_cell(arch_id: str, shape_name: str, mesh, strategy: str = "megatron",
                 donate_argnums=(2,) if donate else ())
             lowered = jitted.lower(params_shapes, specs["tokens"],
                                    specs["cache"], specs["cache_pos"])
-        # CompiledCompat: cost_analysis() is a list-of-dicts on older jax;
-        # everything downstream (reports, tests) indexes the flat dict.
-        compiled = roofline.CompiledCompat(lowered.compile())
+        compiled = lowered.compile()
     return lowered, compiled, {"cfg": cfg, "shape": shape}
 
 

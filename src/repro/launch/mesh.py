@@ -14,24 +14,10 @@ the layout that scales past 1000 nodes.
 """
 from __future__ import annotations
 
-import inspect
-from typing import Optional, Tuple
+from typing import Tuple
 
 import jax
-
-
-def _axis_type_kwargs(n_axes: int) -> dict:
-    """``axis_types=Auto`` where the jax version supports it.
-
-    ``jax.sharding.AxisType`` (and the ``axis_types`` kwarg on
-    ``jax.make_mesh``) only exist on newer jax; older versions are
-    Auto-by-default, so omitting the kwarg is behavior-identical.
-    """
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if (axis_type is None or
-            "axis_types" not in inspect.signature(jax.make_mesh).parameters):
-        return {}
-    return {"axis_types": (axis_type.Auto,) * n_axes}
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -45,16 +31,24 @@ def make_production_mesh(*, multi_pod: bool = False):
             f"need {n} devices for the production mesh, have {len(devices)} "
             "(the dry-run must set XLA_FLAGS=--xla_force_host_platform_"
             "device_count=512 before any jax import)")
-    return jax.make_mesh(shape, axes, devices=devices,
-                         **_axis_type_kwargs(len(axes)))
+    return make_mesh(shape, axes, devices=devices)
 
 
-def make_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...]):
+def make_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...], devices=None):
     """Arbitrary mesh with GSPMD-auto axis types (tests use small meshes)."""
-    return jax.make_mesh(shape, axes, **_axis_type_kwargs(len(axes)))
+    return jax.make_mesh(shape, axes, (AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def make_host_mesh():
-    """Single-process CPU mesh (trainer/serve on this container)."""
+    """Data-parallel mesh over every local device (trainer, batch engine)."""
     n = jax.device_count()
     return make_mesh((n, 1), ("data", "model"))
+
+
+def make_device_mesh(device=None):
+    """One-device ("data", "model") mesh: where one serving engine places
+    its params and KV pool.  Defaults to the first device, so a one-chip
+    path uses exactly one device even on a host with more."""
+    device = device if device is not None else jax.devices()[0]
+    return make_mesh((1, 1), ("data", "model"), devices=[device])

@@ -21,9 +21,11 @@ from ..quant import QuantPolicy
 from ..roofline.analysis import HARDWARE_PRESETS
 from ..serve.engine import ContinuousEngine, Engine, Request
 from ..serve.kvcache import servable_reasons
+from . import mesh as mesh_lib
+from .cache import enable_compile_cache
 
 
-def main(argv=None):
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True, choices=list(ARCH_IDS))
     ap.add_argument("--full", action="store_true")
@@ -130,16 +132,57 @@ def main(argv=None):
                     choices=["auto"] + sorted(HARDWARE_PRESETS),
                     help="roofline HardwareSpec the profiler attributes "
                          "dispatches against (auto = detect jax backend)")
-    args = ap.parse_args(argv)
+    return ap
+
+
+def quant_policy(args) -> QuantPolicy:
+    return QuantPolicy(kv_dtype=args.kv_dtype,
+                       quant_weights=args.quant_weights,
+                       weight_bits=args.weight_bits)
+
+
+def continuous_engine(cfg, params, args, obs, *, max_seq: int,
+                      device=None) -> ContinuousEngine:
+    """The continuous engine this launcher serves with, placed on one
+    ``device`` (default: the first)."""
+    return ContinuousEngine(
+        cfg, params, max_slots=args.max_batch, max_seq=max_seq,
+        page_size=args.page_size,
+        max_tokens_in_flight=args.max_tokens_in_flight,
+        decode_chunk=args.decode_chunk, sample=args.sample,
+        seed=args.seed, eos_id=args.eos_id,
+        mesh=mesh_lib.make_device_mesh(device),
+        precompute=not args.no_precompute,
+        paged_attn=args.paged_attn,
+        quant=quant_policy(args), obs=obs, admission=args.admission,
+        max_queue=args.max_queue,
+        max_preemptions=args.max_preemptions,
+        shadow_sample=args.shadow_sample)
+
+
+def replica_router(cfg, params, args, obs, *, max_seq: int):
+    """``args.replicas`` continuous engines behind the failover router.
+    Replica i owns device i; replicas share devices round-robin only where
+    there are fewer devices than replicas (a one-device CPU host)."""
+    from ..fleet import EngineReplica, Router
+    devices = jax.devices()
+    pool = [EngineReplica(f"r{i}", continuous_engine(
+                cfg, params, args, obs.scoped(replica=f"r{i}"),
+                max_seq=max_seq, device=devices[i % len(devices)]))
+            for i in range(args.replicas)]
+    return Router(pool, policy=args.router_policy,
+                  hedge_after_s=args.hedge_after, obs=obs, seed=args.seed)
+
+
+def main(argv=None):
+    args = build_parser().parse_args(argv)
+    enable_compile_cache()
 
     getter = get_config if args.full else get_smoke_config
     cfg = getter(args.arch)
     model = build_model(cfg)
     params = model.init(jax.random.PRNGKey(0))
     max_seq = 64 + args.new_tokens
-    quant = QuantPolicy(kv_dtype=args.kv_dtype,
-                        quant_weights=args.quant_weights,
-                        weight_bits=args.weight_bits)
     watchdog = None
     if args.slo:
         from ..obs.slo import SloWatchdog, rules_from_json
@@ -158,31 +201,11 @@ def main(argv=None):
             raise SystemExit(f"[launch.serve] {args.arch} is not continuous-"
                              f"servable ({'; '.join(reasons)}); "
                              f"use --engine batch")
-
-        def make_engine(eng_obs):
-            return ContinuousEngine(
-                cfg, params, max_slots=args.max_batch, max_seq=max_seq,
-                page_size=args.page_size,
-                max_tokens_in_flight=args.max_tokens_in_flight,
-                decode_chunk=args.decode_chunk, sample=args.sample,
-                seed=args.seed, eos_id=args.eos_id,
-                precompute=not args.no_precompute,
-                paged_attn=args.paged_attn,
-                quant=quant, obs=eng_obs, admission=args.admission,
-                max_queue=args.max_queue,
-                max_preemptions=args.max_preemptions,
-                shadow_sample=args.shadow_sample)
-
         if args.replicas > 1:
-            from ..fleet import EngineReplica, Router
-            pool = [EngineReplica(f"r{i}",
-                                  make_engine(obs.scoped(replica=f"r{i}")))
-                    for i in range(args.replicas)]
-            router = Router(pool, policy=args.router_policy,
-                            hedge_after_s=args.hedge_after, obs=obs,
-                            seed=args.seed)
+            router = replica_router(cfg, params, args, obs, max_seq=max_seq)
         else:
-            engine = make_engine(obs)
+            engine = continuous_engine(cfg, params, args, obs,
+                                       max_seq=max_seq)
     else:
         if args.kv_dtype != "f32":
             print(f"[launch.serve] note: --kv-dtype {args.kv_dtype} applies "
@@ -196,7 +219,7 @@ def main(argv=None):
                         precompute=not args.no_precompute,
                         decode_mode=args.decode_mode, eos_id=args.eos_id,
                         seed=args.seed, bucket_prompts=not args.no_bucket,
-                        quant=quant, obs=obs)
+                        quant=quant_policy(args), obs=obs)
     rng = np.random.RandomState(0)
     # prompts cover the smoke sliding window (16): the ring-buffer prefill
     # keeps the window tail and needs S >= window for SWA archs

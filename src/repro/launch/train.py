@@ -20,6 +20,7 @@ from ..obs import Obs
 from ..optim import adamw
 from ..train.trainer import Trainer
 from . import mesh as mesh_lib
+from .cache import enable_compile_cache
 
 
 def main():
@@ -44,6 +45,7 @@ def main():
     ap.add_argument("--metrics-every", type=int, default=10,
                     help="with --metrics-out: flush every N steps")
     args = ap.parse_args()
+    enable_compile_cache()
 
     getter = get_config if args.full else get_smoke_config
     cfg = getter(args.arch, compress=not args.no_compress)

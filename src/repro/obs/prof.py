@@ -6,8 +6,8 @@ same way.  `repro.obs` (PR 6) gave the engines wall-clock spans and
 `repro.roofline` gave the dry-run static cost cells — this module connects
 them: every engine dispatch kind (per-bucket prefill, ``decode_chunk``)
 carries the FLOP and byte counts of its *compiled executable*, captured
-ONCE at compile time via ``roofline.CompiledCompat``'s normalized
-``cost_analysis()``, and every fenced dispatch then derives
+ONCE at compile time from its ``cost_analysis()``, and every fenced
+dispatch then derives
 
     achieved FLOP/s   = flops / dt
     achieved bytes/s  = bytes_accessed / dt
@@ -41,7 +41,7 @@ from collections import deque
 from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 from ..roofline.analysis import (HARDWARE_PRESETS, HardwareSpec,
-                                 CompiledCompat, detect_hardware)
+                                 detect_hardware)
 from .metrics import Gauge, Registry, flat_name
 
 # Log-spaced FLOP/s + bytes/s buckets covering host CPUs through TPU pods.
@@ -111,12 +111,11 @@ class Profiler:
     def register(self, kind: str, compiled) -> DispatchCost:
         """Capture a compiled executable's static cost under ``kind``.
 
-        ``cost_analysis()`` is normalized across jax versions by
-        ``roofline.CompiledCompat``.  Re-registering a kind (the batch
-        engine recompiles per shape) overwrites the static cost; the
-        histograms accumulate across shapes of the kind.
+        Re-registering a kind (the batch engine recompiles per shape)
+        overwrites the static cost; the histograms accumulate across
+        shapes of the kind.
         """
-        ca = CompiledCompat(compiled).cost_analysis()
+        ca = compiled.cost_analysis()
         flops = float(ca.get("flops", 0.0))
         nbytes = float(ca.get("bytes accessed", 0.0))
         cost = DispatchCost(
@@ -149,26 +148,20 @@ class Profiler:
             self.samples.setdefault(key, [])
 
     # -- hot path (once per fenced dispatch) ------------------------------
-    def on_dispatch(self, cost: Optional[DispatchCost], t0_s: float,
+    def on_dispatch(self, cost: DispatchCost, t0_s: float,
                     t1_s: float) -> None:
         """Record one fenced dispatch: ``t0_s``/``t1_s`` are obs-clock
         marks stamped around the device program (the engines fence with
-        ``block_until_ready`` before ``t1``).  ``cost`` None (AOT capture
-        unavailable) still logs the timeline event, just uncosted."""
+        ``block_until_ready`` before ``t1``)."""
         if not self.enabled:
             return
-        frac = None
-        if cost is not None:
-            dt = max(t1_s - t0_s, 1e-9)
-            h_flops, h_bytes, h_frac = self._hists[cost.kind]
-            h_flops.observe(cost.flops / dt)
-            h_bytes.observe(cost.bytes_accessed / dt)
-            frac = cost.bound_s / dt
-            h_frac.observe(frac)
-            kind = cost.kind
-        else:
-            kind = "uncosted"
-        self.events.append((kind, t0_s, t1_s, frac))
+        dt = max(t1_s - t0_s, 1e-9)
+        h_flops, h_bytes, h_frac = self._hists[cost.kind]
+        h_flops.observe(cost.flops / dt)
+        h_bytes.observe(cost.bytes_accessed / dt)
+        frac = cost.bound_s / dt
+        h_frac.observe(frac)
+        self.events.append((cost.kind, t0_s, t1_s, frac))
         for key, gauge in self._watched:
             self.samples[key].append((t1_s, gauge.value))
 
@@ -244,7 +237,7 @@ class ScopedProfiler:
         merged.update({k: str(v) for k, v in labels.items()})
         self.base.watch(name, **merged)
 
-    def on_dispatch(self, cost: Optional[DispatchCost], t0_s: float,
+    def on_dispatch(self, cost: DispatchCost, t0_s: float,
                     t1_s: float) -> None:
         self.base.on_dispatch(cost, t0_s, t1_s)
 
@@ -259,30 +252,19 @@ class ScopedProfiler:
 # ---------------------------------------------------------------------------
 # AOT capture: compile once, profile forever
 # ---------------------------------------------------------------------------
-def aot_compile(jitfn, args: Sequence, profiler: Optional[Profiler],
-                kind: str) -> Tuple[Callable, Optional[DispatchCost]]:
+def aot_compile(jitfn, args: Sequence, profiler: Profiler,
+                kind: str) -> Tuple[Callable, DispatchCost]:
     """Lower + compile a ``jax.jit`` function for concrete ``args`` and
     register the executable's cost under ``kind``.
 
     The returned callable is the compiled executable itself — calling it is
     the same one-compile cost path ``jitfn(*args)`` would have taken, but
     the engine now holds the object whose ``cost_analysis()`` the profiler
-    read (donation hints survive ``lower``).  If AOT lowering fails (an
-    exotic backend / jax version), the jit wrapper is returned unchanged
-    and the dispatch kind simply goes uncosted — profiling must never take
-    the serving path down.
+    read (donation hints survive ``lower``).  A lowering or compile error
+    raises: the program the device would run is the one that failed.
     """
-    try:
-        compiled = jitfn.lower(*args).compile()
-    except Exception:                                  # pragma: no cover
-        return jitfn, None
-    cost = None
-    if profiler is not None:
-        try:
-            cost = profiler.register(kind, compiled)
-        except Exception:                              # pragma: no cover
-            cost = None
-    return compiled, cost
+    compiled = jitfn.lower(*args).compile()
+    return compiled, profiler.register(kind, compiled)
 
 
 def resolve_hardware(name: Optional[str]) -> HardwareSpec:

@@ -135,7 +135,9 @@ class ParityRunner:
     def run(self, prompt, new_tokens: int) -> Dict:
         """Teacher-forced decode of ``new_tokens`` steps on one prompt;
         both paths consume the ORACLE's greedy token each step.  Returns
-        ``steps`` / ``greedy_agreement`` / ``max_logit_drift``."""
+        ``steps`` / ``greedy_agreement`` / ``max_logit_drift``, and the
+        oracle logits' ``oracle_logit_absmax`` (the scale drift is read
+        against)."""
         prompt = np.asarray(prompt, np.int32)
         S = len(prompt)
         new_tokens = max(int(new_tokens), 1)
@@ -161,6 +163,7 @@ class ParityRunner:
 
         agree = [int(first_q) == tok]
         drift = 0.0
+        absmax = float(jnp.max(jnp.abs(logits[0, -1])))
         for j in range(new_tokens - 1):
             pos = S + j
             lo, cache = self._step_o(self.params_o,
@@ -172,11 +175,13 @@ class ParityRunner:
             lo32 = np.asarray(lo[0, -1], np.float32)
             lq32 = np.asarray(lq[0, -1], np.float32)
             drift = max(drift, float(np.abs(lq32 - lo32).max()))
+            absmax = max(absmax, float(np.abs(lo32).max()))
             agree.append(int(lq32.argmax()) == int(lo32.argmax()))
             tok = int(lo32.argmax())           # teacher forcing: oracle token
         return {"steps": len(agree),
                 "greedy_agreement": float(np.mean(agree)),
-                "max_logit_drift": drift}
+                "max_logit_drift": drift,
+                "oracle_logit_absmax": absmax}
 
 
 def parity_report(cfg: ArchConfig, params, *, policy: QuantPolicy,

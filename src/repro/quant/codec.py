@@ -320,14 +320,19 @@ def page_scatter(pool_q: jax.Array, scales: jax.Array, pid: jax.Array,
     duplicate trash writes are unordered but trash content and trash
     scale are never read unmasked.
 
-    Because scales only GROW, the serving telemetry can count grow events
-    without threading a counter through the jit'd loop: the continuous
-    engine diffs host shadows of the scale leaves around decode
-    dispatches into the ``quant.scale_growths`` counter
-    (docs/observability.md).
+    A write at offset 0 is a slot's first write into a page it was just
+    given (decode advances one position at a time), so the page's old
+    scale and resident values belong to a previous owner: they are taken
+    as zero, and a recycled page quantizes exactly like a fresh one.
+
+    Because scales only GROW while a page has one owner, the serving
+    telemetry can count grow events without threading a counter through
+    the jit'd loop: the continuous engine diffs host shadows of the scale
+    leaves around decode dispatches into the ``quant.scale_growths``
+    counter (docs/observability.md).
     """
     page = pool_q.shape[1]
-    s_old = scales[pid]                                        # (B, H)
+    s_old = jnp.where((off == 0)[:, None], 0.0, scales[pid])   # (B, H)
     s_new = jnp.maximum(s_old, absmax_scale(x, axes=-1))       # (B, H)
 
     def requant(carry):
@@ -343,7 +348,8 @@ def page_scatter(pool_q: jax.Array, scales: jax.Array, pid: jax.Array,
 
     def fast(carry):
         pq, sc = carry                                         # s_new == s_old
-        return pq.at[pid, off].set(quantize(x, s_old[..., None])), sc
+        return (pq.at[pid, off].set(quantize(x, s_new[..., None])),
+                sc.at[pid].set(s_new))
 
     return jax.lax.cond(jnp.any(s_new > s_old), requant, fast,
                         (pool_q, scales))

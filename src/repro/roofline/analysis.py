@@ -72,14 +72,24 @@ HARDWARE_PRESETS = {s.name: s for s in (TPU_V5E, TPU_V4, HOST_CPU,
                                         GPU_GENERIC)}
 
 
-def detect_hardware() -> HardwareSpec:
-    """Preset for the active jax backend (host-CPU default)."""
-    backend = jax.default_backend()
-    if backend == "tpu":
-        return TPU_V5E
-    if backend == "gpu":
-        return GPU_GENERIC
-    return HOST_CPU
+# Accelerator presets keyed on ``Device.device_kind`` as JAX reports it.
+DEVICE_KIND_PRESETS = {"TPU v5 lite": TPU_V5E, "TPU v4": TPU_V4}
+
+
+def detect_hardware(device=None) -> HardwareSpec:
+    """Preset for ``device`` (default: the first device).  The host preset
+    covers platform ``cpu`` only; an accelerator whose ``device_kind`` has
+    no preset raises rather than borrow another part's peaks."""
+    device = device if device is not None else jax.devices()[0]
+    if device.platform == "cpu":
+        return HOST_CPU
+    try:
+        return DEVICE_KIND_PRESETS[device.device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no hardware preset for {device.platform} device kind "
+            f"{device.device_kind!r}: add its peaks to "
+            f"DEVICE_KIND_PRESETS") from None
 
 
 # Legacy module constants (EXPERIMENTS.md numbers were computed from these);
@@ -93,38 +103,6 @@ _DTYPE_BYTES = {
     "s32": 4, "u32": 4, "f32": 4, "s64": 8, "u64": 8, "f64": 8,
     "c64": 8, "c128": 16, "f8e4m3fn": 1, "f8e5m2": 1, "s4": 1, "u4": 1,
 }
-
-def xla_cost_analysis(compiled) -> Dict[str, float]:
-    """``compiled.cost_analysis()`` as a flat dict across jax versions.
-
-    Older jax returns a one-element list of per-program dicts (multi-program
-    executables return several — summed here, matching the newer flat-dict
-    semantics); newer jax returns the dict directly.  Idempotent.
-    """
-    ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        merged: Dict[str, float] = {}
-        for prog in ca:
-            for k, v in (prog or {}).items():
-                merged[k] = merged.get(k, 0.0) + v
-        return merged
-    return dict(ca or {})
-
-
-class CompiledCompat:
-    """Delegating view of a compiled executable whose ``cost_analysis()`` is
-    normalized via ``xla_cost_analysis`` — so downstream report code (and
-    EXPERIMENTS.md numbers) can always index ``["flops"]``."""
-
-    def __init__(self, compiled):
-        self._compiled = compiled
-
-    def __getattr__(self, name):
-        return getattr(self._compiled, name)
-
-    def cost_analysis(self) -> Dict[str, float]:
-        return xla_cost_analysis(self._compiled)
-
 
 _COLLECTIVES = ("all-gather", "all-reduce", "reduce-scatter", "all-to-all",
                 "collective-permute", "collective-broadcast")
@@ -279,7 +257,7 @@ def cell_report(lowered, compiled, cfg: ArchConfig, shape: ShapeSpec,
     chips = int(np.prod(mesh.devices.shape))
     sizes = dict(zip(mesh.axis_names, mesh.devices.shape))
     dp_size = sizes.get("pod", 1) * sizes.get("data", 1)
-    ca = xla_cost_analysis(compiled)
+    ca = compiled.cost_analysis()
     slstm_extra = (slstm_scan_correction(cfg, shape, dp_size)
                    if cfg.unroll_scan else 0.0)
     flops = float(ca.get("flops", 0.0)) + slstm_extra

@@ -28,6 +28,7 @@ in both engines, so no weight FFT executes inside any serve program.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import time
 from typing import Dict, List, Optional, Sequence
@@ -390,9 +391,15 @@ class ContinuousEngine:
                              f"{'; '.join(reasons)} — use Engine")
         self.cfg = cfg
         self.quant = quant or QuantPolicy()
+        # the engine's devices: one unless the caller hands it a wider
+        # mesh; params replicate over it, the pool shards over it (below)
+        self.mesh = (mesh if mesh is not None
+                     else mesh_lib.make_device_mesh())
         raw_params = params                 # pre-precompute tree (shadow
         self.params = (precompute_serving_params(params, cfg, self.quant)
                        if precompute else params)  # oracle replays from it)
+        self.params = jax.device_put(self.params, jax.sharding.NamedSharding(
+            self.mesh, jax.sharding.PartitionSpec()))
         self.max_slots = max_slots
         self.max_seq = max_seq
         self.page_size = page_size
@@ -422,7 +429,6 @@ class ContinuousEngine:
         if max_tokens_in_flight < max_seq + 1:
             raise ValueError(f"max_tokens_in_flight {max_tokens_in_flight} "
                              f"cannot admit one max_seq request")
-        self.mesh = mesh if mesh is not None else mesh_lib.make_host_mesh()
         # keep the page dim DP-divisible, else page_pool_spec's fallback
         # would replicate the whole pool over the data-parallel devices
         num_pages = dist_sharding.dp_round_up(num_pages, self.mesh)
@@ -430,7 +436,7 @@ class ContinuousEngine:
         self.pool = kvc.build_pool(cfg, num_pages, page_size, self.quant)
         # pin the pool to its derived layout (pages over DP, heads over
         # "model" — the dense cache's placement, see dist/sharding.py);
-        # trivial on the 1-device host mesh, load-bearing on real meshes
+        # trivial on a one-device mesh, load-bearing on wider ones
         self.pool = jax.device_put(self.pool, dist_sharding.to_shardings(
             dist_sharding.pool_specs(self.pool, self.mesh), self.mesh))
         # telemetry (repro.obs): the registry backs stats(); the allocator
@@ -554,6 +560,15 @@ class ContinuousEngine:
         # (obs/slo.py rate rules skip the baseline-less first snapshot)
         self.obs.baseline()
 
+    @contextlib.contextmanager
+    def _on_mesh(self):
+        """Trace under the engine's activation policy, and make host
+        inputs (tokens, tables, positions) on the engine's first device
+        rather than the process default."""
+        with dist_ctx.activation_policy(self.mesh), \
+                jax.default_device(self.mesh.devices.flat[0]):
+            yield
+
     # -- jit caches -------------------------------------------------------
     def _prefill_exec(self, n_pages: int, args) -> tuple:
         """(compiled callable, DispatchCost|None) for a page bucket —
@@ -650,7 +665,7 @@ class ContinuousEngine:
         future stays queued).  Returns True if anything happened — the
         low-level API the chaos harness drives; ``generate`` is a loop
         over this."""
-        with dist_ctx.activation_policy(self.mesh):
+        with self._on_mesh():
             now = self._now()
             return self._step(now, arrived_before=now)
 
@@ -665,7 +680,7 @@ class ContinuousEngine:
             self._finish_unserved(entry.order, entry.request,
                                   entry.resume_tokens, REJECTED,
                                   preemptions=entry.preemptions)
-        with dist_ctx.activation_policy(self.mesh):
+        with self._on_mesh():
             while not self.scheduler.idle:
                 if not self._step(self._now()):
                     raise RuntimeError("drain stall: in-flight work cannot "
@@ -686,6 +701,13 @@ class ContinuousEngine:
         ``fleet.EngineReplica`` folds into its DEGRADED transitions."""
         return int(self._c_anom.value)
 
+    @property
+    def programs_compiled(self) -> int:
+        """Device programs compiled so far (one per prefill bucket, plus
+        the decode loop): ``fleet.EngineReplica`` does not time a step in
+        which this grew."""
+        return len(self._prefills) + (self._loop_exec is not None)
+
     # -- serving loop -----------------------------------------------------
     def generate(self, reqs: Sequence[Request],
                  arrival_times: Optional[Sequence[float]] = None
@@ -700,7 +722,7 @@ class ContinuousEngine:
                else [float(a) for a in arrival_times])
         orders = [self.submit(r, a) for r, a in zip(reqs, arr)]
         gate = arrival_times is not None
-        with dist_ctx.activation_policy(self.mesh):
+        with self._on_mesh():
             while not self.scheduler.idle:
                 now = self._now()
                 if gate and not self.scheduler.running and \

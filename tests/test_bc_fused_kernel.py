@@ -1,5 +1,5 @@
 """Fused three-phase block-circulant Pallas kernel vs the pure-jnp oracle,
-swept over shapes/dtypes (interpret mode), plus the REPRO_KERNELS dispatch
+swept over shapes/dtypes (interpret mode), plus the platform dispatch
 through kernels/ops.py."""
 import jax
 import jax.numpy as jnp
@@ -48,7 +48,7 @@ def test_fused_kernel_grid_tiling():
 # ---------------------------------------------------------------------------
 # Dispatch policy: bc_linear_fused routes through ops.py like the other two
 # kernels — 'off' lowers to the XLA cached-spectral path, 'interpret' runs
-# the Pallas body, and the env var drives the default.
+# the Pallas body, and the platform drives the default.
 # ---------------------------------------------------------------------------
 def test_ops_dispatch_off_matches_interpret():
     w = cc.init_block_circulant(jax.random.PRNGKey(0), 64, 96, 16)
@@ -65,9 +65,10 @@ def test_ops_dispatch_off_matches_interpret():
 def test_ops_dispatch_env_default(monkeypatch):
     w = cc.init_block_circulant(jax.random.PRNGKey(0), 32, 32, 16)
     x = jax.random.normal(jax.random.PRNGKey(1), (3, 32))
-    monkeypatch.setenv("REPRO_KERNELS", "off")
-    assert kops.kernel_mode() == "off"
+    assert kops.kernel_mode() == "off"              # CPU backend: XLA
     out = kops.bc_linear_fused(x, w, 32)
     np.testing.assert_allclose(np.asarray(out),
                                np.asarray(cc.bc_matmul_direct(x, w, 32)),
                                rtol=2e-3, atol=2e-3)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert kops.kernel_mode() == "tpu"              # TPU backend: Pallas

@@ -221,6 +221,28 @@ def test_health_step_timeout_degrades_then_downs():
     assert not rep.step()              # DOWN replicas are inert
 
 
+def test_health_step_that_compiles_is_not_a_timeout():
+    """A step in which the engine compiled a new device program (the first
+    dispatch of a prefill bucket or of the decode loop) is slow, not hung:
+    it neither degrades nor downs the replica; the next slow step without a
+    compile does."""
+    eng = FakeEngine()
+    eng.programs_compiled = 0
+
+    def compile_one():
+        eng.programs_compiled += 1
+        return True
+    eng.step_fn = compile_one
+    rep = EngineReplica("r0", eng, step_timeout_s=1.0, down_after=1,
+                        clock=_ticking_clock(1.1))
+    for _ in range(3):
+        rep.step()
+    assert rep.state == HEALTHY and rep.consecutive_timeouts == 0
+    eng.step_fn = lambda: True
+    rep.step()
+    assert rep.state == DOWN and "hung" in rep.down_reason
+
+
 def test_health_anomaly_degrades_and_recovers():
     eng = FakeEngine()
     rep = EngineReplica("r0", eng, step_timeout_s=10.0, recover_after=2,

@@ -275,6 +275,10 @@ class _FakeMesh:
         self.axis_names = axes
 
 
+# a spec entry sharded over the one DP axis, as PartitionSpec normalises it
+DP_ENTRY = jax.sharding.PartitionSpec(("data",))[0]
+
+
 def test_page_pool_spec_rules():
     mesh = _FakeMesh((16, 16), ("data", "model"))
     # (n, P, page, Hkv, D): pages over DP, heads indivisible -> head_dim
@@ -298,7 +302,7 @@ def test_dp_round_up_keeps_page_dim_shardable():
     n = sharding.dp_round_up(32 * 16 + 1, mesh)        # 513 -> 528
     assert n % 16 == 0 and n >= 513
     spec = sharding.page_pool_spec((2, n, 16, 16, 32), mesh)
-    assert spec[1] == ("data",)
+    assert spec[1] == DP_ENTRY
     # no DP axes (or size-1): identity
     assert sharding.dp_round_up(7, _FakeMesh((1, 4), ("data", "model"))) == 7
 
@@ -314,7 +318,7 @@ def test_pool_specs_match_dense_cache_story():
     for spec in jax.tree.leaves(specs,
                                 is_leaf=lambda x: isinstance(
                                     x, jax.sharding.PartitionSpec)):
-        assert spec[1] == ("data",)          # page-id dim over DP
+        assert spec[1] == DP_ENTRY           # page-id dim over DP
         assert spec[2] is None               # in-page offset never sharded
     table = jnp.zeros((4, 8), jnp.int32)
     assert sharding.pool_specs(table, mesh) == jax.sharding.PartitionSpec()

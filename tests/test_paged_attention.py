@@ -111,14 +111,15 @@ def test_streamed_matches_gather_oracle(case):
 
 
 def test_dispatch_env_default(monkeypatch):
-    """REPRO_KERNELS drives the dispatch like every other kernel."""
+    """The platform drives the default dispatch like every other kernel:
+    the XLA stream on the CPU, the kernel wherever kernel_mode says so."""
     rng = np.random.RandomState(1)
     case = _pool_case(rng, num_pages=6, page=4, Hkv=2, G=2, D=8,
                       positions=[5, 9])
     q, pk, pv, tab, pos, _ = case
-    monkeypatch.setenv("REPRO_KERNELS", "off")
+    assert kops.kernel_mode() == "off"
     off = kops.paged_attention(q, pk, pv, tab, pos)
-    monkeypatch.setenv("REPRO_KERNELS", "interpret")
+    monkeypatch.setattr(kops, "kernel_mode", lambda: "interpret")
     interp = kops.paged_attention(q, pk, pv, tab, pos)
     np.testing.assert_allclose(np.asarray(off), np.asarray(interp),
                                rtol=2e-5, atol=2e-5)
@@ -190,14 +191,14 @@ def test_engine_stream_matches_gather_and_oracle(tiny_setup):
 
 
 def test_engine_interpret_mode_matches_oracle(tiny_setup, monkeypatch):
-    """REPRO_KERNELS=interpret runs the Pallas flash-decode kernel inside
+    """A kernel_mode of 'interpret' runs the Pallas flash-decode kernel inside
     the real decode loop (slot recycling included) and still emits the
     oracle's greedy tokens."""
     cfg, params = tiny_setup
     reqs = _reqs([(20, 13), (12, 21), (16, 17)])
     oracle = Engine(cfg, params, max_batch=1, max_seq=32)
     want = [oracle.generate([r])[0]["tokens"] for r in reqs]
-    monkeypatch.setenv("REPRO_KERNELS", "interpret")
+    monkeypatch.setattr(kops, "kernel_mode", lambda: "interpret")
     eng = ContinuousEngine(cfg, params, max_slots=2, max_seq=32,
                            page_size=4, decode_chunk=5)
     assert [g["tokens"] for g in eng.generate(reqs)] == want

@@ -20,8 +20,8 @@ from repro.obs.chrometrace import (PID_ENGINE, PID_REQUESTS, build_trace,
                                    write_trace)
 from repro.obs.metrics import Gauge, Registry
 from repro.obs.prof import DispatchCost, Profiler
-from repro.roofline.analysis import (HARDWARE_PRESETS, HOST_CPU, TPU_V5E,
-                                     HardwareSpec, detect_hardware)
+from repro.roofline.analysis import (HARDWARE_PRESETS, HOST_CPU, TPU_V4,
+                                     TPU_V5E, HardwareSpec, detect_hardware)
 from repro.serve.engine import ContinuousEngine, Engine, Request
 
 _GATE_PATH = os.path.join(os.path.dirname(__file__), "..", "benchmarks",
@@ -45,6 +45,37 @@ def test_hardware_presets():
     assert resolve_hardware("auto") is detect_hardware()
     with pytest.raises(ValueError):
         resolve_hardware("abacus")
+
+
+def test_detect_hardware_keys_on_device_kind():
+    """Peaks come from the device kind JAX reports; the host preset covers
+    platform cpu only, and an accelerator with no preset is an error."""
+    from types import SimpleNamespace as Dev
+    assert detect_hardware() is HOST_CPU                 # this CPU backend
+    assert detect_hardware(Dev(platform="cpu", device_kind="cpu")) \
+        is HOST_CPU
+    assert detect_hardware(Dev(platform="tpu",
+                               device_kind="TPU v5 lite")) is TPU_V5E
+    assert detect_hardware(Dev(platform="tpu", device_kind="TPU v4")) \
+        is TPU_V4
+    with pytest.raises(ValueError, match="TPU v9"):
+        detect_hardware(Dev(platform="tpu", device_kind="TPU v9"))
+    with pytest.raises(ValueError, match="H100"):
+        detect_hardware(Dev(platform="gpu", device_kind="H100"))
+
+
+def test_aot_compile_raises_on_compile_error():
+    """A program the backend refuses fails loudly instead of falling back
+    to an uncosted jit wrapper: here the Pallas TPU kernel, lowered without
+    interpret mode, on the CPU."""
+    from repro.kernels.paged_attention import paged_attention_kernel
+    pool = jnp.ones((2, 4, 1, 8))
+    args = (jnp.ones((1, 2, 8)), pool, pool, jnp.ones((1, 1), jnp.int32),
+            jnp.zeros((1,), jnp.int32))
+    prof = Profiler(Registry(), hardware=HOST_CPU)
+    with pytest.raises(ValueError, match="interpret"):
+        aot_compile(jax.jit(paged_attention_kernel), args, prof, "pa")
+    assert "pa" not in prof.costs
 
 
 def test_dispatch_cost_bound_sides():

@@ -322,16 +322,46 @@ def test_engine_int8_kv_greedy_parity(arch):
 
 
 def test_engine_bf16_pool_matches_f32_oracle():
-    """bf16 pool storage keeps greedy token identity on the tie-free arch
-    (the no-regression guard for the non-quantized dtypes)."""
+    """bf16 pool storage stays within a stated logit tolerance of the f32
+    dense-cache oracle (the no-regression guard for the non-quantized
+    dtypes).  Logits, not greedy tokens: a bf16 round-off may flip a
+    near-tie, which is a legitimate difference and not a regression.
+
+    Tolerance: 0.05 absolute on the teacher-forced logits — about 4x the
+    measured bf16 drift of this model (~0.013) and the drift of the int8
+    pool; an f32 pool drifts ~2e-6."""
     cfg = get_smoke_config("tinyllama-1.1b").replace(dtype="float32")
     params = build_model(cfg).init(jax.random.PRNGKey(0))
+    for seed in (0, 1):
+        rep = calibrate.parity_report(cfg, params,
+                                      policy=QuantPolicy("bf16"),
+                                      prompt_len=20, new_tokens=16,
+                                      seed=seed)
+        assert rep["max_logit_drift"] < 0.05, rep
+    # the engine serves the bf16 pool end to end, first tokens (f32
+    # prefill logits in both) identical to the oracle's
     reqs = _reqs([(20, 8), (12, 10)])
     oracle = Engine(cfg, params, max_batch=1, max_seq=32)
     want = [oracle.generate([r])[0]["tokens"] for r in reqs]
     eng = ContinuousEngine(cfg, params, max_slots=2, max_seq=32, page_size=4,
                            quant=QuantPolicy("bf16"))
-    assert [g["tokens"] for g in eng.generate(reqs)] == want
+    got = [g["tokens"] for g in eng.generate(reqs)]
+    assert [len(g) for g in got] == [len(w) for w in want]
+    assert [g[0] for g in got] == [w[0] for w in want]
+
+
+def test_engine_int8_pool_recycled_pages_serve_like_fresh():
+    """A decode-grown page keeps no scale from its previous owner: serving
+    the same requests again on the same int8 engine (every page now
+    recycled) emits the same tokens as on the fresh pool."""
+    cfg = get_smoke_config("tinyllama-1.1b").replace(dtype="float32")
+    params = build_model(cfg).init(jax.random.PRNGKey(0))
+    eng = ContinuousEngine(cfg, params, max_slots=2, max_seq=32, page_size=4,
+                           decode_chunk=5, quant=QuantPolicy("int8"))
+    reqs = _reqs([(20, 8), (12, 10), (16, 6), (9, 12)])
+    first = [g["tokens"] for g in eng.generate(reqs)]
+    second = [g["tokens"] for g in eng.generate(list(reversed(reqs)))]
+    assert list(reversed(second)) == first
 
 
 def test_engine_quant_telemetry():
@@ -380,8 +410,9 @@ def test_pool_specs_route_scales_and_int8_payloads():
     def walk(snode, pnode):
         if isinstance(snode, dict) and "k" in snode:
             # int8 payloads still shard: pages over DP, offset unsharded
-            assert snode["k"][1] == ("data",) and snode["k"][2] is None
-            assert snode["k_scale"][1] == ("data",)
+            dp = P(("data",))[0]                  # as PartitionSpec keeps it
+            assert snode["k"][1] == dp and snode["k"][2] is None
+            assert snode["k_scale"][1] == dp
             assert len(snode["k_scale"]) == 3     # no in-page-offset dim
         elif isinstance(snode, (list, tuple)):
             for s, p_ in zip(snode, pnode):
