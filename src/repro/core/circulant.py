@@ -347,14 +347,15 @@ def bc_matmul_fused(x: jax.Array, ws, n_outs, mode: str = "train",
     """
     ps = [w.shape[-3] for w in ws]
     k = ws[0].shape[-1]
-    if mode == "train":
-        w_cat = jnp.concatenate(ws, axis=-3)           # (Σp_i, q, k)
-        y = bc_matmul_fft(x, w_cat, w_cat.shape[-3] * k, gauss=gauss)
-    else:
-        if cache is None:
-            cache = fused_spectral_cache(ws, gauss)
-        y = bc_matmul_spectral(x, cache, k, cache["wr"].shape[-3] * k,
-                               gauss, kernel_fn)
+    with jax.named_scope("spectral"):
+        if mode == "train":
+            w_cat = jnp.concatenate(ws, axis=-3)       # (Σp_i, q, k)
+            y = bc_matmul_fft(x, w_cat, w_cat.shape[-3] * k, gauss=gauss)
+        else:
+            if cache is None:
+                cache = fused_spectral_cache(ws, gauss)
+            y = bc_matmul_spectral(x, cache, k, cache["wr"].shape[-3] * k,
+                                   gauss, kernel_fn)
     outs = []
     off = 0
     for p_i, n_out in zip(ps, n_outs):
@@ -412,25 +413,30 @@ def init_linear(key, n_in: int, n_out: int, spec: LinearSpec,
 
 def apply_linear(params: Dict[str, jax.Array], x: jax.Array, spec: LinearSpec,
                  n_out: int, mode: str = "train", kernel_fn=None) -> jax.Array:
-    """y = x W (+ b). For block-circulant, W is the (n_out×n_in) generator."""
+    """y = x W (+ b). For block-circulant, W is the (n_out×n_in) generator.
+    Its ops run under the named scope ``dense`` or ``spectral`` (the
+    device trace's layer kinds, repro.obs.scopes)."""
     if spec.kind == "dense":
-        y = x @ params["w"].astype(x.dtype)
+        with jax.named_scope("dense"):
+            y = x @ params["w"].astype(x.dtype)
     else:
         path = spec.resolve_path(mode)
         w = params["wc"]
         # Precomputed spectral planes (serve/params.py) short-circuit the
         # weight-side FFT; training must keep differentiating through `wc`,
         # so the cache is consulted only outside train mode.
-        if mode != "train" and "wc_cache" in params:
-            y = bc_matmul_spectral(x, params["wc_cache"], spec.block_size,
-                                   n_out, spec.gauss, kernel_fn)
-        elif path == "direct":
-            y = bc_matmul_direct(x, w, n_out)
-        elif path == "spectral":
-            y = bc_matmul_spectral(x, spectral_cache(w, spec.gauss),
-                                   spec.block_size, n_out, spec.gauss, kernel_fn)
-        else:
-            y = bc_matmul_fft(x, w, n_out, gauss=spec.gauss)
+        with jax.named_scope("spectral"):
+            if mode != "train" and "wc_cache" in params:
+                y = bc_matmul_spectral(x, params["wc_cache"], spec.block_size,
+                                       n_out, spec.gauss, kernel_fn)
+            elif path == "direct":
+                y = bc_matmul_direct(x, w, n_out)
+            elif path == "spectral":
+                y = bc_matmul_spectral(x, spectral_cache(w, spec.gauss),
+                                       spec.block_size, n_out, spec.gauss,
+                                       kernel_fn)
+            else:
+                y = bc_matmul_fft(x, w, n_out, gauss=spec.gauss)
     if "b" in params:
         y = y + params["b"].astype(y.dtype)
     return y

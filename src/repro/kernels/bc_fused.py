@@ -67,6 +67,7 @@ def bc_fused_matmul(xb: jax.Array, wr, ws1, ws2, *, k: int,
         out_specs=y_spec,
         out_shape=jax.ShapeDtypeStruct((B, p, k), xb.dtype),
         interpret=interpret,
+        name="bc_fused",
     )(xb, wr, ws1, ws2, Cr, Ci, Dr, Di)
 
 

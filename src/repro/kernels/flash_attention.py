@@ -104,4 +104,5 @@ def flash_attention(q, k, v, *, causal=True, window=0, softcap=0.0,
             pltpu.VMEM((bq, D), jnp.float32),   # output accumulator
         ],
         interpret=interpret,
+        name="flash_attention",
     )(q, k, v)

@@ -59,5 +59,6 @@ def paged_gather_kernel(pool: jax.Array, table: jax.Array,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, maxp, page, H, D), pool.dtype),
         interpret=interpret,
+        name="paged_gather",
     )(table, pool)
     return out.reshape(B, maxp * page, H, D)

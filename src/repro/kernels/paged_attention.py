@@ -257,4 +257,5 @@ def paged_attention_kernel(q, pool_k, pool_v, table, positions, *,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
+        name="paged_attention",
     )(table, positions, *operands)

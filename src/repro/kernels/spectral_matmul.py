@@ -61,5 +61,6 @@ def spectral_matmul(xr, xi, wr, ws1, ws2, *, block_b: int = 128,
         out_specs=[y_spec, y_spec],
         out_shape=out_shape,
         interpret=interpret,
+        name="spectral_matmul",
     )(xr, xi, wr, ws1, ws2)
     return yr, yi

@@ -270,18 +270,23 @@ def attention_block(params, x, *, cfg, causal=True, window=0,
         pid = jnp.where(cache_pos >= 0,
                         block_table[jnp.arange(B), col], 0)   # 0 = trash page
         off = q_pos0 % page
-        if "k_scale" in cache:                   # int8 pool (repro.quant):
-            from ..quant import codec as qcodec  # per-(page, head) absmax
-            pool_k, k_sc = qcodec.page_scatter(  # scatter, requantize-on-grow
-                pool_k, cache["k_scale"], pid, off, k[:, 0])
-            pool_v, v_sc = qcodec.page_scatter(
-                pool_v, cache["v_scale"], pid, off, v[:, 0])
-            new_cache = {"k": pool_k, "v": pool_v,
-                         "k_scale": k_sc, "v_scale": v_sc}
-        else:
-            pool_k = pool_k.at[pid, off].set(k[:, 0].astype(pool_k.dtype))
-            pool_v = pool_v.at[pid, off].set(v[:, 0].astype(pool_v.dtype))
-            new_cache = {"k": pool_k, "v": pool_v}
+        with jax.named_scope("kv_write"):
+            if "k_scale" in cache:
+                # int8 pool (repro.quant): per-(page, head) absmax
+                # scatter, requantize-on-grow
+                from ..quant import codec as qcodec
+                pool_k, k_sc = qcodec.page_scatter(
+                    pool_k, cache["k_scale"], pid, off, k[:, 0])
+                pool_v, v_sc = qcodec.page_scatter(
+                    pool_v, cache["v_scale"], pid, off, v[:, 0])
+                new_cache = {"k": pool_k, "v": pool_v,
+                             "k_scale": k_sc, "v_scale": v_sc}
+            else:
+                pool_k = pool_k.at[pid, off].set(
+                    k[:, 0].astype(pool_k.dtype))
+                pool_v = pool_v.at[pid, off].set(
+                    v[:, 0].astype(pool_v.dtype))
+                new_cache = {"k": pool_k, "v": pool_v}
         if paged_impl == "stream":
             # fused paged flash-decode: pages stream through the online
             # softmax (dequantizing in-register on the int8 lane); the
@@ -303,34 +308,35 @@ def attention_block(params, x, *, cfg, causal=True, window=0,
             idx = jnp.arange(k.shape[1])[None, :]
             kv_positions = jnp.where(idx <= cache_pos[:, None], idx, -1)
     elif cache is not None and cross_kv is None:
-        Smax = cache["k"].shape[1]
-        if window and Smax <= window:                    # ring buffer (SWA)
-            if S == 1:                                   # decode: single slot
-                slot = cache_pos % Smax
+        with jax.named_scope("kv_write"):
+            Smax = cache["k"].shape[1]
+            if window and Smax <= window:                # ring buffer (SWA)
+                if S == 1:                               # decode: one slot
+                    slot = cache_pos % Smax
+                    upd = lambda c, new: jax.lax.dynamic_update_slice(
+                        c, new.astype(c.dtype), (0, slot, 0, 0))
+                    kc, vc = upd(cache["k"], k), upd(cache["v"], v)
+                    pos_c = jax.lax.dynamic_update_slice(
+                        cache["pos"],
+                        positions[0].astype(cache["pos"].dtype), (slot,))
+                    new_cache = {"k": kc, "v": vc, "pos": pos_c}
+                    k, v, kv_positions = kc, vc, pos_c
+                else:                                    # prefill: keep tail
+                    assert S >= Smax, "SWA prefill shorter than window"
+                    kc = k[:, -Smax:].astype(cache["k"].dtype)
+                    vc = v[:, -Smax:].astype(cache["v"].dtype)
+                    pos_c = positions[0][-Smax:].astype(cache["pos"].dtype)
+                    new_cache = {"k": kc, "v": vc, "pos": pos_c}
+            else:                                        # linear cache
                 upd = lambda c, new: jax.lax.dynamic_update_slice(
-                    c, new.astype(c.dtype), (0, slot, 0, 0))
+                    c, new.astype(c.dtype), (0, cache_pos, 0, 0))
                 kc, vc = upd(cache["k"], k), upd(cache["v"], v)
                 pos_c = jax.lax.dynamic_update_slice(
                     cache["pos"], positions[0].astype(cache["pos"].dtype),
-                    (slot,))
+                    (cache_pos,))
                 new_cache = {"k": kc, "v": vc, "pos": pos_c}
-                k, v, kv_positions = kc, vc, pos_c
-            else:                                        # prefill: keep tail
-                assert S >= Smax, "SWA prefill shorter than window"
-                kc = k[:, -Smax:].astype(cache["k"].dtype)
-                vc = v[:, -Smax:].astype(cache["v"].dtype)
-                pos_c = positions[0][-Smax:].astype(cache["pos"].dtype)
-                new_cache = {"k": kc, "v": vc, "pos": pos_c}
-        else:                                            # linear cache
-            upd = lambda c, new: jax.lax.dynamic_update_slice(
-                c, new.astype(c.dtype), (0, cache_pos, 0, 0))
-            kc, vc = upd(cache["k"], k), upd(cache["v"], v)
-            pos_c = jax.lax.dynamic_update_slice(
-                cache["pos"], positions[0].astype(cache["pos"].dtype),
-                (cache_pos,))
-            new_cache = {"k": kc, "v": vc, "pos": pos_c}
-            if S == 1:                                   # decode reads cache
-                k, v, kv_positions = kc, vc, pos_c
+                if S == 1:                               # decode reads cache
+                    k, v, kv_positions = kc, vc, pos_c
 
     if streamed is not None:
         o = streamed

@@ -111,26 +111,30 @@ def apply_block(params, x, kind: str, cfg: ArchConfig, *, mode: str,
     aux = jnp.zeros((), jnp.float32)
     new_cache = cache
     if kind in ATTN_KINDS:
-        h = norm_lib.apply_norm(cfg.norm, params["ln1"], x)
-        a, new_cache = attn_lib.attention_block(
-            params["attn"], h, cfg=cfg, causal=True,
-            window=_window_for(kind, cfg), cache=cache, cache_pos=cache_pos,
-            mode=mode, q_chunk=q_chunk, kv_chunk=kv_chunk,
-            block_table=block_table, paged_impl=paged_impl)
-        if "ln1_post" in params:
-            a = norm_lib.apply_norm(cfg.norm, params["ln1_post"], a)
-        x = x + a
-        h = norm_lib.apply_norm(cfg.norm, params["ln2"], x)
-        if kind in ("moe", "moe_swa"):
-            f, aux = ffn_lib.moe(params["moe"], h, d_ff=cfg.d_ff,
-                                 moe_cfg=cfg.moe, comp=comp,
-                                 activation=cfg.ffn_activation, mode=mode)
-        else:
-            f = ffn_lib.mlp(params["mlp"], h, d_ff=cfg.d_ff, comp=comp,
-                            activation=cfg.ffn_activation, mode=mode)
-        if "ln2_post" in params:
-            f = norm_lib.apply_norm(cfg.norm, params["ln2_post"], f)
-        x = x + f
+        with jax.named_scope("attention"):
+            h = norm_lib.apply_norm(cfg.norm, params["ln1"], x)
+            a, new_cache = attn_lib.attention_block(
+                params["attn"], h, cfg=cfg, causal=True,
+                window=_window_for(kind, cfg), cache=cache,
+                cache_pos=cache_pos, mode=mode, q_chunk=q_chunk,
+                kv_chunk=kv_chunk, block_table=block_table,
+                paged_impl=paged_impl)
+            if "ln1_post" in params:
+                a = norm_lib.apply_norm(cfg.norm, params["ln1_post"], a)
+            x = x + a
+        moe = kind in ("moe", "moe_swa")
+        with jax.named_scope("moe" if moe else "mlp"):
+            h = norm_lib.apply_norm(cfg.norm, params["ln2"], x)
+            if moe:
+                f, aux = ffn_lib.moe(params["moe"], h, d_ff=cfg.d_ff,
+                                     moe_cfg=cfg.moe, comp=comp,
+                                     activation=cfg.ffn_activation, mode=mode)
+            else:
+                f = ffn_lib.mlp(params["mlp"], h, d_ff=cfg.d_ff, comp=comp,
+                                activation=cfg.ffn_activation, mode=mode)
+            if "ln2_post" in params:
+                f = norm_lib.apply_norm(cfg.norm, params["ln2_post"], f)
+            x = x + f
     elif kind == "rec":
         width = cfg.recurrent.lru_width or cfg.d_model
         h = norm_lib.apply_norm(cfg.norm, params["ln1"], x)
@@ -230,71 +234,80 @@ def forward(params, tokens, cfg: ArchConfig, *, mode: str = "train",
     kv_chunk = kv_chunk or cfg.attn_kv_chunk
     segs = segments_for(cfg)
     dtype = jnp.bfloat16 if cfg.dtype == "bfloat16" else jnp.float32
-    x = emb_lib.embed(params["embed"], tokens,
-                      scale_by_dim=cfg.name.startswith(("gemma", "recurrent")))
-    x = x.astype(dtype)
-    if frontend_embeds is not None:
-        # modality stub: precomputed patch/frame embeddings replace the first
-        # `num_patches` token slots (see DESIGN.md §Arch-applicability).
-        np_ = frontend_embeds.shape[1]
-        x = jnp.concatenate([frontend_embeds.astype(dtype), x[:, np_:]], axis=1)
-    if "pos" in params:
-        pos0 = 0 if cache_pos is None else cache_pos
-        S = x.shape[1]
-        table = params["pos"]["pos"]
-        idx = pos0 + jnp.arange(S)
-        x = x + table[idx].astype(dtype)[None]
+    with jax.named_scope("embed"):
+        x = emb_lib.embed(params["embed"], tokens, scale_by_dim=cfg.name
+                          .startswith(("gemma", "recurrent")))
+        x = x.astype(dtype)
+        if frontend_embeds is not None:
+            # modality stub: precomputed patch/frame embeddings replace the
+            # first `num_patches` token slots (DESIGN.md
+            # §Arch-applicability).
+            np_ = frontend_embeds.shape[1]
+            x = jnp.concatenate([frontend_embeds.astype(dtype),
+                                 x[:, np_:]], axis=1)
+        if "pos" in params:
+            pos0 = 0 if cache_pos is None else cache_pos
+            S = x.shape[1]
+            table = params["pos"]["pos"]
+            idx = pos0 + jnp.arange(S)
+            x = x + table[idx].astype(dtype)[None]
 
     aux_total = jnp.zeros((), jnp.float32)
     new_caches: List = []
-    for si, (pattern, n) in enumerate(segs):
-        seg_params = params["segments"][si]
-        seg_cache = None if cache is None else cache[si]
+    with jax.named_scope("layers"):
+        for si, (pattern, n) in enumerate(segs):
+            seg_params = params["segments"][si]
+            seg_cache = None if cache is None else cache[si]
 
-        def group_fn(carry, xs):
-            x_, aux_ = carry
-            gp, gc = xs
-            new_gc = []
-            for bi, kind in enumerate(pattern):
-                bp = gp[bi]
-                c_in = None if gc is None else gc[bi]
-                x_ = shard_act(x_)          # block-boundary sharding pin
-                x_, c_out, aux_b = apply_block(
-                    bp, x_, kind, cfg, mode=mode, cache=c_in,
-                    cache_pos=cache_pos, q_chunk=q_chunk, kv_chunk=kv_chunk,
-                    block_table=block_table, paged_impl=paged_impl)
-                new_gc.append(c_out)
-                aux_ = aux_ + aux_b
-            x_ = shard_act(x_)
-            new_gc = tuple(new_gc) if gc is not None else 0
-            return (x_, aux_), new_gc
+            def group_fn(carry, xs):
+                x_, aux_ = carry
+                gp, gc = xs
+                new_gc = []
+                for bi, kind in enumerate(pattern):
+                    bp = gp[bi]
+                    c_in = None if gc is None else gc[bi]
+                    x_ = shard_act(x_)          # block-boundary sharding pin
+                    x_, c_out, aux_b = apply_block(
+                        bp, x_, kind, cfg, mode=mode, cache=c_in,
+                        cache_pos=cache_pos, q_chunk=q_chunk,
+                        kv_chunk=kv_chunk, block_table=block_table,
+                        paged_impl=paged_impl)
+                    new_gc.append(c_out)
+                    aux_ = aux_ + aux_b
+                x_ = shard_act(x_)
+                new_gc = tuple(new_gc) if gc is not None else 0
+                return (x_, aux_), new_gc
 
-        if cfg.remat == "full" and mode == "train":
-            group_fn = jax.checkpoint(group_fn,
-                                      policy=jax.checkpoint_policies.nothing_saveable)
-        if cfg.unroll_scan:
-            # python loop over groups: exact cost_analysis / collective
-            # counts for the roofline lowering (a while body is costed once)
-            outs = []
-            for g in range(n):
-                gp = jax.tree.map(lambda a: a[g], seg_params)
-                gc = (None if seg_cache is None else
-                      jax.tree.map(lambda a: a[g], seg_cache))
-                (x, aux_total), new_gc = group_fn((x, aux_total), (gp, gc))
-                outs.append(new_gc)
-            new_seg_cache = (jax.tree.map(lambda *a: jnp.stack(a), *outs)
-                            if seg_cache is not None else None)
-        elif seg_cache is not None:
-            (x, aux_total), new_seg_cache = jax.lax.scan(
-                group_fn, (x, aux_total), (seg_params, seg_cache))
-        else:
-            (x, aux_total), _ = jax.lax.scan(
-                lambda c, gp: group_fn(c, (gp, None)), (x, aux_total),
-                seg_params)
-            new_seg_cache = None
-        new_caches.append(new_seg_cache)
+            if cfg.remat == "full" and mode == "train":
+                group_fn = jax.checkpoint(
+                    group_fn, policy=jax.checkpoint_policies.nothing_saveable)
+            if cfg.unroll_scan:
+                # python loop over groups: exact cost_analysis / collective
+                # counts for the roofline lowering (a while body is costed
+                # once)
+                outs = []
+                for g in range(n):
+                    gp = jax.tree.map(lambda a: a[g], seg_params)
+                    gc = (None if seg_cache is None else
+                          jax.tree.map(lambda a: a[g], seg_cache))
+                    (x, aux_total), new_gc = group_fn((x, aux_total),
+                                                      (gp, gc))
+                    outs.append(new_gc)
+                new_seg_cache = (jax.tree.map(lambda *a: jnp.stack(a), *outs)
+                                if seg_cache is not None else None)
+            elif seg_cache is not None:
+                (x, aux_total), new_seg_cache = jax.lax.scan(
+                    group_fn, (x, aux_total), (seg_params, seg_cache))
+            else:
+                (x, aux_total), _ = jax.lax.scan(
+                    lambda c, gp: group_fn(c, (gp, None)), (x, aux_total),
+                    seg_params)
+                new_seg_cache = None
+            new_caches.append(new_seg_cache)
 
-    x = norm_lib.apply_norm(cfg.norm, params["final_norm"], x)
-    logits = emb_lib.logits(params["embed"], x, softcap=cfg.logit_softcap)
+    with jax.named_scope("final_norm"):
+        x = norm_lib.apply_norm(cfg.norm, params["final_norm"], x)
+    with jax.named_scope("lm_head"):
+        logits = emb_lib.logits(params["embed"], x, softcap=cfg.logit_softcap)
     return logits, {"moe_aux": aux_total}, (new_caches if cache is not None
                                             else None)

@@ -169,6 +169,18 @@ class Histogram:
                         else self.max)
         return self.max
 
+    def mark(self) -> int:
+        """A position in the retained values: hand it to ``values_since``
+        to read what was observed after this call (a window's values
+        without the warm-up's)."""
+        return len(self._values)
+
+    def values_since(self, mark: int) -> List[float]:
+        """Values observed after ``mark`` (from ``mark()``), in order.
+        Only retained values are returned: past ``keep`` observations the
+        list stops growing."""
+        return list(self._values[mark:])
+
     @staticmethod
     def of(values: Sequence[float],
            bounds: Sequence[float] = SECONDS_BUCKETS) -> "Histogram":

@@ -65,12 +65,13 @@ def make_decode_step(cfg: ArchConfig, sample: bool = False,
                                               cache_pos)
         if logits_sharding is not None:
             logits = jax.lax.with_sharding_constraint(logits, logits_sharding)
-        if sample:
-            key = jax.random.fold_in(base_key, cache_pos)
-            nxt = jax.random.categorical(
-                key, logits[:, -1].astype(jnp.float32) / temperature, -1)
-        else:
-            nxt = jnp.argmax(logits[:, -1], axis=-1)
+        with jax.named_scope("sample"):
+            if sample:
+                key = jax.random.fold_in(base_key, cache_pos)
+                nxt = jax.random.categorical(
+                    key, logits[:, -1].astype(jnp.float32) / temperature, -1)
+            else:
+                nxt = jnp.argmax(logits[:, -1], axis=-1)
         return logits, nxt.astype(jnp.int32), new_cache
     return decode_step
 
@@ -229,23 +230,27 @@ def make_prefill_pack_step(cfg: ArchConfig, n_pages: int,
     def prefill_pack(params, batch, pool, pages, true_len):
         cache = model.init_cache(1, spad, dtype=jnp.float32)
         logits, dense = model.prefill(params, batch, cache)
-        last = jax.lax.dynamic_index_in_dim(logits[0], true_len - 1, 0,
-                                            keepdims=False)
-        ok = jnp.all(jnp.isfinite(last))
-        nxt = jnp.argmax(last, axis=-1).astype(jnp.int32)
+        with jax.named_scope("sample"):
+            last = jax.lax.dynamic_index_in_dim(logits[0], true_len - 1, 0,
+                                                keepdims=False)
+            ok = jnp.all(jnp.isfinite(last))
+            nxt = jnp.argmax(last, axis=-1).astype(jnp.int32)
+        stats = None
+        with jax.named_scope("kv_write"):
+            if capture_stats:
+                pool, clipped, total = kvc.pack_prefill_cache(
+                    pool, dense, pages, page_size, true_len=true_len,
+                    with_stats=True)
+            else:
+                pool = kvc.pack_prefill_cache(pool, dense, pages, page_size,
+                                              true_len=true_len)
         if capture_stats:
-            pool, clipped, total = kvc.pack_prefill_cache(
-                pool, dense, pages, page_size, true_len=true_len,
-                with_stats=True)
-            stats = jnp.concatenate([
-                logit_stats(last),
-                jnp.stack([jnp.asarray(clipped, jnp.float32),
-                           jnp.asarray(total, jnp.float32)]),
-                cache_group_absmax(dense)])
-        else:
-            pool = kvc.pack_prefill_cache(pool, dense, pages, page_size,
-                                          true_len=true_len)
-            stats = None
+            with jax.named_scope("health"):
+                stats = jnp.concatenate([
+                    logit_stats(last),
+                    jnp.stack([jnp.asarray(clipped, jnp.float32),
+                               jnp.asarray(total, jnp.float32)]),
+                    cache_group_absmax(dense)])
         return nxt, ok, pool, stats
     return prefill_pack
 
@@ -317,22 +322,23 @@ def make_paged_decode_loop(cfg: ArchConfig, chunk: int, *,
                                          paged_impl=paged_impl)
         if logits_sharding is not None:
             logits = jax.lax.with_sharding_constraint(logits, logits_sharding)
-        finite = (jnp.all(jnp.isfinite(logits[:, -1]), axis=-1)
-                  if nan_guard else jnp.ones(cur.shape[0], bool))
         lastlg = logits[:, -1] if capture_stats else None
-        if sample:
-            # fold in slot index AND position: slots at the same position
-            # (e.g. identical prompts admitted together) must not draw from
-            # identical PRNG noise
-            slots = jnp.arange(cur.shape[0])
-            keys = jax.vmap(lambda s, p: jax.random.fold_in(
-                jax.random.fold_in(base_key, s), p))(
-                slots, jnp.maximum(pos_masked, 0))
-            nxt = jax.vmap(lambda k, lg: jax.random.categorical(
-                k, lg.astype(jnp.float32) / temperature, -1))(
-                keys, logits[:, -1])
-        else:
-            nxt = jnp.argmax(logits[:, -1], axis=-1)
+        with jax.named_scope("sample"):
+            finite = (jnp.all(jnp.isfinite(logits[:, -1]), axis=-1)
+                      if nan_guard else jnp.ones(cur.shape[0], bool))
+            if sample:
+                # fold in slot index AND position: slots at the same
+                # position (e.g. identical prompts admitted together) must
+                # not draw from identical PRNG noise
+                slots = jnp.arange(cur.shape[0])
+                keys = jax.vmap(lambda s, p: jax.random.fold_in(
+                    jax.random.fold_in(base_key, s), p))(
+                    slots, jnp.maximum(pos_masked, 0))
+                nxt = jax.vmap(lambda k, lg: jax.random.categorical(
+                    k, lg.astype(jnp.float32) / temperature, -1))(
+                    keys, logits[:, -1])
+            else:
+                nxt = jnp.argmax(logits[:, -1], axis=-1)
         return nxt.astype(jnp.int32), finite, pool, lastlg
 
     def decode_loop(params, cur, pool, table, pos, rem):
@@ -363,10 +369,11 @@ def make_paged_decode_loop(cfg: ArchConfig, chunk: int, *,
                 # keep the latest FINITE active row per slot (a poisoned
                 # row never lands in the sample); non-finite accounting
                 # is exact because ``bad`` rides the per-step NaN guard
-                upd = (~halt & finite)[:, None]
-                lastrow = jnp.where(upd, lastlg.astype(jnp.float32),
-                                    lastrow)
-                stats_ = (lastrow, nonf + bad.astype(jnp.float32))
+                with jax.named_scope("health"):
+                    upd = (~halt & finite)[:, None]
+                    lastrow = jnp.where(upd, lastlg.astype(jnp.float32),
+                                        lastrow)
+                    stats_ = (lastrow, nonf + bad.astype(jnp.float32))
             tok = jnp.where(halt, jnp.int32(fill), nxt)
             buf_ = jax.lax.dynamic_update_slice(buf_, tok[:, None], (0, j))
             pos_ = jnp.where(halt, pos_, pos_ + 1)
@@ -383,6 +390,7 @@ def make_paged_decode_loop(cfg: ArchConfig, chunk: int, *,
             cond_fn, body_fn, st)
         if capture_stats:
             lastrow, nonf = stats
-            stats = logit_stats(lastrow).at[:, 3].set(nonf)
+            with jax.named_scope("health"):
+                stats = logit_stats(lastrow).at[:, 3].set(nonf)
         return buf, cur, pool, pos, rem, done, anom, stats
     return decode_loop

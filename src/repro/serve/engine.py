@@ -45,6 +45,7 @@ from ..models import transformer as tfm
 from ..models.registry import build_model
 from ..obs import BYTES_BUCKETS, RATIO_BUCKETS, Obs, aot_compile
 from ..obs.health import SCALE_BUCKETS, HealthPlane, ShadowOracle
+from ..obs.scopes import program_scopes
 from ..quant.codec import QuantPolicy, plane_clip_report
 from . import decode as dec
 from . import kvcache as kvc
@@ -483,6 +484,7 @@ class ContinuousEngine:
         self._loop_exec = None
         self.nan_guard = nan_guard
         self._prefills: Dict[int, tuple] = {}
+        self._scopes: Dict[str, Dict] = {}  # op_scopes(), by kind
         self._cur = np.zeros(max_slots, np.int32)
         self._pos = np.zeros(max_slots, np.int32)
         self._rem = np.zeros(max_slots, np.int32)
@@ -492,6 +494,13 @@ class ContinuousEngine:
         self._c_anom = reg.counter("engine.anomalies")
         self._h_prefill = reg.histogram("engine.prefill_dispatch_s")
         self._h_chunk = reg.histogram("engine.decode_chunk_s")
+        # host time of a step in which the device had nothing queued: the
+        # step's span less its fences (engine.*.fence), one value per step
+        # that dispatched a decode chunk
+        self._h_step_host = reg.histogram("engine.step_host_s")
+        self._fence_s = 0.0                 # fence seconds in this step
+        self._c_h2d = reg.counter("engine.host_transfers", dir="h2d")
+        self._c_d2h = reg.counter("engine.host_transfers", dir="d2h")
         self._h_occup = reg.histogram("sched.slot_occupancy",
                                       bounds=RATIO_BUCKETS)
         self._h_attn_bytes = reg.histogram("attn.bytes_per_token",
@@ -525,6 +534,10 @@ class ContinuousEngine:
                              if self._capture and self.quant.kv_quantized
                              else None)
         self._h_scale = {}
+        # device->host reads of one pool_scale_map (one per scale leaf)
+        self._scale_leaves = sum(
+            getattr(path[-1], "key", None) in ("k_scale", "v_scale")
+            for path, _ in jax.tree_util.tree_leaves_with_path(self.pool))
         if self._scales_host is not None:
             for k in ("k_scale", "v_scale"):
                 self._h_scale[k] = reg.histogram("quant." + k,
@@ -579,10 +592,27 @@ class ContinuousEngine:
             jitfn = jax.jit(dec.make_prefill_pack_step(
                 self.cfg, n_pages, self.page_size,
                 capture_stats=self._capture), donate_argnums=(2,))
-            ent = aot_compile(jitfn, args, self.obs.profiler,
-                              dec.prefill_kind(n_pages))
+            with self.obs.span("engine.compile"):
+                ent = aot_compile(jitfn, args, self.obs.profiler,
+                                  dec.prefill_kind(n_pages))
             self._prefills[n_pages] = ent
         return ent
+
+    def op_scopes(self) -> Dict[str, Dict]:
+        """The op->scope map of every program compiled so far, by dispatch
+        kind (``decode_chunk``, ``prefill_{n}p``): HLO instruction name ->
+        its ``metadata op_name`` path, parsed from the executables this
+        engine holds (repro.obs.scopes).  Built when asked and kept, off
+        the serving path; read after a traced window to put the trace's
+        device ops under their layer kinds."""
+        progs = {dec.prefill_kind(n): ent[0]
+                 for n, ent in self._prefills.items()}
+        if self._loop_exec is not None:
+            progs[dec.DECODE_CHUNK_KIND] = self._loop_exec[0]
+        for kind, compiled in progs.items():
+            if kind not in self._scopes:
+                self._scopes[kind] = program_scopes(compiled)
+        return dict(self._scopes)
 
     # -- public lifecycle API ---------------------------------------------
     def _now(self) -> float:
@@ -752,49 +782,72 @@ class ContinuousEngine:
 
     def _step(self, now_s: float,
               arrived_before: Optional[float] = None) -> bool:
-        """One scheduler round between device dispatches."""
+        """One scheduler round between device dispatches, in the host span
+        ``engine.step``; with a decode chunk dispatched, its host time
+        less the fences is one ``engine.step_host_s`` value."""
+        t0 = time.perf_counter()
+        self._fence_s = 0.0
+        with self.obs.span("engine.step"):
+            progress, decoded = self._step_phases(now_s, arrived_before)
+        if decoded and self.obs.enabled:
+            self._h_step_host.observe(time.perf_counter() - t0
+                                      - self._fence_s)
+        return progress
+
+    def _fence(self, name: str, outs) -> None:
+        """``block_until_ready`` in the span ``name``, its seconds kept
+        out of the step's host time."""
+        t0 = time.perf_counter()
+        with self.obs.span(name):
+            jax.block_until_ready(outs)
+        self._fence_s += time.perf_counter() - t0
+
+    def _step_phases(self, now_s: float, arrived_before: Optional[float]):
+        """(progress, whether a decode chunk was dispatched)."""
         sched = self.scheduler
         progress = False
-        # 1. queued deadlines
-        for entry in sched.expire_queue(now_s):
-            self._finish_unserved(entry.order, entry.request,
-                                  entry.resume_tokens, TIMEOUT,
-                                  preemptions=entry.preemptions)
-            progress = True
-        # 2. pending cancels of running slots (queued cancels resolved
-        #    inside cancel(); stale ids — already terminal — are dropped)
-        if self._cancels:
-            for slot in list(sched.running):
-                if slot.request.id in self._cancels:
-                    self._finish(slot, CANCELLED)
-                    progress = True
-            self._cancels.clear()
-        # 3. in-flight deadlines
-        for slot in list(sched.running):
-            if slot.deadline_s is not None and now_s > slot.deadline_s:
-                self._finish(slot, TIMEOUT)
+        with self.obs.span("sched.admit"):
+            # 1. queued deadlines
+            for entry in sched.expire_queue(now_s):
+                self._finish_unserved(entry.order, entry.request,
+                                      entry.resume_tokens, TIMEOUT,
+                                      preemptions=entry.preemptions)
                 progress = True
-        # 4. admission + prefill (recompute-prefill for preempted entries)
-        admitted = sched.try_admit(now_s, arrived_before)
-        for entry in sched.drain_doomed():   # can NEVER fit the pool
-            self._finish_unserved(entry.order, entry.request,
-                                  entry.resume_tokens, FAILED,
-                                  preemptions=entry.preemptions)
-            progress = True
+            # 2. pending cancels of running slots (queued cancels resolved
+            #    inside cancel(); stale ids — already terminal — dropped)
+            if self._cancels:
+                for slot in list(sched.running):
+                    if slot.request.id in self._cancels:
+                        self._finish(slot, CANCELLED)
+                        progress = True
+                self._cancels.clear()
+            # 3. in-flight deadlines
+            for slot in list(sched.running):
+                if slot.deadline_s is not None and now_s > slot.deadline_s:
+                    self._finish(slot, TIMEOUT)
+                    progress = True
+            # 4. admission (recompute-prefill for preempted entries)
+            admitted = sched.try_admit(now_s, arrived_before)
+            for entry in sched.drain_doomed():   # can NEVER fit the pool
+                self._finish_unserved(entry.order, entry.request,
+                                      entry.resume_tokens, FAILED,
+                                      preemptions=entry.preemptions)
+                progress = True
         for slot in admitted:
             self._prefill_slot(slot)
             progress = True
         # 5. page growth for the next chunk; preemptions free their victim's
         #    device state
-        prep = sched.prepare_decode(self.decode_chunk)
-        t_pre = self.obs.rebase(time.perf_counter())
-        for idx, entry in prep.preempted:
-            self._rem[idx] = 0              # victim's slot is dead on device
-            progress = True
-            if self.obs.enabled:
-                tr = self._traces.get(entry.order)
-                if tr is not None:
-                    tr.mark_preempt(t_pre, len(entry.resume_tokens))
+        with self.obs.span("sched.grow"):
+            prep = sched.prepare_decode(self.decode_chunk)
+            t_pre = self.obs.rebase(time.perf_counter())
+            for idx, entry in prep.preempted:
+                self._rem[idx] = 0          # victim's slot is dead on device
+                progress = True
+                if self.obs.enabled:
+                    tr = self._traces.get(entry.order)
+                    if tr is not None:
+                        tr.mark_preempt(t_pre, len(entry.resume_tokens))
         # 6. decode dispatch over the slots whose pages cover the chunk
         if admitted or prep.preempted or prep.runnable:
             self._stall_streak = 0
@@ -815,36 +868,56 @@ class ContinuousEngine:
                 self._stall_streak = 0
         if self._shadow is not None:
             self._shadow.tick()     # at most one replay, off the hot path
-        self.obs.tick()             # emitter rides the dispatch cadence
-        return progress
+        with self.obs.span("obs.tick"):
+            self.obs.tick()         # emitter rides the dispatch cadence
+        return progress, bool(prep.runnable)
 
     def _prefill_slot(self, slot) -> None:
+        with self.obs.span("engine.prefill", order=slot.order):
+            self._prefill_slot_spans(slot)
+
+    def _prefill_slot_spans(self, slot) -> None:
         t0 = time.perf_counter()
-        req = slot.request
-        # a resumed (preempted) request teacher-forces prompt + generated
-        # tokens through prefill: greedy decode then continues identically
-        prompt = list(np.asarray(req.prompt).tolist()) + list(slot.tokens)
-        S = len(prompt)
-        n_pages = kvc.pages_for(S, self.page_size)
-        spad = n_pages * self.page_size
-        toks = np.zeros(spad, np.int32)
-        toks[:S] = prompt                              # right-pad
-        batch = {"tokens": jnp.asarray(toks[None])}
-        if self.cfg.frontend == "vision_stub":
-            batch["patches"] = jnp.zeros(
-                (1, self.cfg.num_patches, self.cfg.d_model), jnp.float32)
-        pages = jnp.asarray(self.block_table.pages(slot.index)[:n_pages],
-                            jnp.int32)
-        fn, cost = self._prefill_exec(
-            n_pages, (self.params, batch, self.pool, pages, jnp.int32(S)))
-        nxt, ok, self.pool, pstats = fn(
-            self.params, batch, self.pool, pages, jnp.int32(S))
+        with self.obs.span("engine.prefill.launch"):
+            req = slot.request
+            # a resumed (preempted) request teacher-forces prompt +
+            # generated tokens through prefill: greedy decode then
+            # continues identically
+            prompt = (list(np.asarray(req.prompt).tolist())
+                      + list(slot.tokens))
+            S = len(prompt)
+            n_pages = kvc.pages_for(S, self.page_size)
+            spad = n_pages * self.page_size
+            toks = np.zeros(spad, np.int32)
+            toks[:S] = prompt                          # right-pad
+            batch = {"tokens": jnp.asarray(toks[None])}
+            if self.cfg.frontend == "vision_stub":
+                batch["patches"] = jnp.zeros(
+                    (1, self.cfg.num_patches, self.cfg.d_model), jnp.float32)
+            pages = jnp.asarray(
+                self.block_table.pages(slot.index)[:n_pages], jnp.int32)
+            true_len = jnp.int32(S)
+            self._c_h2d.inc(3)                 # tokens, pages, true_len
+            args = (self.params, batch, self.pool, pages, true_len)
+            fn, cost = self._prefill_exec(n_pages, args)
+            nxt, ok, self.pool, pstats = fn(*args)
         # fence the whole dispatch (token, page scatter AND the numerics
         # side-output) so the prefill span — and the trace's first-token
         # mark — measure device work, not a later host sync
-        jax.block_until_ready((nxt, self.pool) if pstats is None
-                              else (nxt, self.pool, pstats))
+        self._fence("engine.prefill.fence",
+                    (nxt, self.pool) if pstats is None
+                    else (nxt, self.pool, pstats))
         t1 = time.perf_counter()
+        with self.obs.span("engine.prefill.fetch"):
+            # the device packs the health side-output into ONE flat vector
+            # [logit(4) | kv_clipped | kv_total | act_absmax...]: a single
+            # device->host transfer per prefill, not four
+            arr = (np.asarray(pstats, dtype=np.float64)
+                   if self._health is not None and pstats is not None
+                   else None)
+            ok_h = bool(ok) if self.nan_guard else True
+            first = int(nxt)
+            self._c_d2h.inc(1 + self.nan_guard + (arr is not None))
         self.obs.profiler.on_dispatch(cost, self.obs.rebase(t0),
                                       self.obs.rebase(t1))
         dt = t1 - t0
@@ -852,22 +925,19 @@ class ContinuousEngine:
         self._ctr["prompt_tokens"].inc(S)
         self._ctr["padded_prompt_tokens"].inc(spad)
         slot.prefill_s = dt
-        if self._health is not None and pstats is not None:
+        if arr is not None:
             # fold BEFORE the guard branch: a poisoned prefill must bump
-            # health.nonfinite_* in the same dispatch the guard retires it.
-            # The device packs everything into ONE flat vector
-            # [logit(4) | kv_clipped | kv_total | act_absmax...] so this
-            # is a single device->host transfer per prefill, not four.
-            arr = np.asarray(pstats, dtype=np.float64)
-            self._health.on_prefill({"logit": arr[:4],
-                                     "act_absmax": arr[6:]})
-            kv_total = float(arr[5])
-            if kv_total > 0:
-                self._c_kv_clip.inc(float(arr[4]))
-                self._c_kv_total.inc(kv_total)
-                self._g_kv_clip.set(self._c_kv_clip.value
-                                    / max(self._c_kv_total.value, 1.0))
-        if self.nan_guard and not bool(ok):
+            # health.nonfinite_* in the same dispatch the guard retires it
+            with self.obs.span("health.fold"):
+                self._health.on_prefill({"logit": arr[:4],
+                                         "act_absmax": arr[6:]})
+                kv_total = float(arr[5])
+                if kv_total > 0:
+                    self._c_kv_clip.inc(float(arr[4]))
+                    self._c_kv_total.inc(kv_total)
+                    self._g_kv_clip.set(self._c_kv_clip.value
+                                        / max(self._c_kv_total.value, 1.0))
+        if not ok_h:
             # poisoned prefill: never stream a garbage first token
             self._c_anom.inc()
             self._rem[slot.index] = 0
@@ -879,7 +949,6 @@ class ContinuousEngine:
                                   + slot.admit_s)
             self._finish(slot, FAILED)
             return
-        first = int(nxt)
         slot.tokens.append(first)
         slot.pos = S                       # position of the token in flight
         slot.budget -= 1
@@ -904,6 +973,7 @@ class ContinuousEngine:
                 # census the freshly written scales into the saturation
                 # histograms
                 new = kvc.pool_scale_map(self.pool)
+                self._c_d2h.inc(self._scale_leaves)
                 for k, h in self._h_scale.items():
                     fresh = new[k][(new[k] != self._scales_host[k])
                                    & (new[k] > 0)]
@@ -929,101 +999,114 @@ class ContinuousEngine:
                 self.pool = poison_slot_pages(
                     self.pool, self.block_table.pages(victim.index)[0])
         t0 = time.perf_counter()
-        # stalled slots (no pages for the next chunk) are masked out of
-        # this dispatch: rem=0 freezes them on device, their budget is
-        # restored afterwards so they retry next round
-        rem_dispatch = self._rem.copy()
-        for s in stalled:
-            rem_dispatch[s.index] = 0
-        if self._table_version != self.block_table.version:
-            self._dev_table = self.block_table.device_table()
-            self._table_version = self.block_table.version
-        if self._loop_exec is None:
-            self._loop_exec = aot_compile(
-                self._loop,
-                (self.params, jnp.asarray(self._cur), self.pool,
-                 self._dev_table, jnp.asarray(self._pos),
-                 jnp.asarray(rem_dispatch)),
-                self.obs.profiler, dec.DECODE_CHUNK_KIND)
-        loop, loop_cost = self._loop_exec
-        buf, cur, self.pool, pos, rem, done, anom, dstats = loop(
-            self.params, jnp.asarray(self._cur), self.pool,
-            self._dev_table, jnp.asarray(self._pos),
-            jnp.asarray(rem_dispatch))
+        with self.obs.span("engine.decode.launch"):
+            # stalled slots (no pages for the next chunk) are masked out of
+            # this dispatch: rem=0 freezes them on device, their budget is
+            # restored afterwards so they retry next round
+            rem_dispatch = self._rem.copy()
+            for s in stalled:
+                rem_dispatch[s.index] = 0
+            if self._table_version != self.block_table.version:
+                self._dev_table = self.block_table.device_table()
+                self._table_version = self.block_table.version
+                self._c_h2d.inc()
+            args = (self.params, jnp.asarray(self._cur), self.pool,
+                    self._dev_table, jnp.asarray(self._pos),
+                    jnp.asarray(rem_dispatch))
+            self._c_h2d.inc(3)                 # cur, pos, rem
+            if self._loop_exec is None:
+                with self.obs.span("engine.compile"):
+                    self._loop_exec = aot_compile(
+                        self._loop, args, self.obs.profiler,
+                        dec.DECODE_CHUNK_KIND)
+            loop, loop_cost = self._loop_exec
+            buf, cur, self.pool, pos, rem, done, anom, dstats = loop(*args)
         # fence before the span boundary: the decode_chunk wall time (and
         # the per-chunk trace marks) measure the device program — the
         # numerics side-output fences with it, so the health fold below
         # is a pure host read
-        jax.block_until_ready(buf if dstats is None else (buf, dstats))
+        self._fence("engine.decode.fence",
+                    buf if dstats is None else (buf, dstats))
         t1 = time.perf_counter()
-        self.obs.profiler.on_dispatch(loop_cost, self.obs.rebase(t0),
-                                      self.obs.rebase(t1))
-        buf = np.asarray(buf)
-        self._cur = np.array(cur)
-        self._pos = np.array(pos)
-        rem_after = np.array(rem)
-        done = np.asarray(done)
-        anom = np.asarray(anom)
-        saved = {s.index: self._rem[s.index] for s in stalled}
-        self._rem = rem_after
-        for idx, v in saved.items():
-            self._rem[idx] = v
-        dt = t1 - t0
-        self._ctr["decode_s"].inc(dt)
-        self._ctr["dispatches"].inc()
-        if self.obs.enabled:
-            self._h_chunk.observe(dt)
-            self._h_occup.observe(len(runnable) / max(self.max_slots, 1))
-            if self._health is not None and dstats is not None:
-                # steps[b] = tokens slot b advanced this dispatch: rows
-                # with 0 still carry init sentinels (or stale maxima from
-                # the donated carry) and are skipped by the fold
-                self._health.on_decode(np.asarray(dstats),
-                                       steps=rem_dispatch - rem_after)
-            if self._scales_host is not None:
-                new = kvc.pool_scale_map(self.pool)
-                grown = 0
-                for k, old in self._scales_host.items():
-                    g = new[k] > old
-                    if g.any():
-                        grown += int(g.sum())
-                        ns, olds = new[k][g], old[g]
-                        # per-element round-off of a rescale is bounded by
-                        # new_scale/2; accumulate the per-group bound
-                        self._c_requant.inc(float(0.5 * ns.sum()))
-                        for s_old, s_new in zip(olds.tolist(), ns.tolist()):
-                            if s_new > 0:
-                                self._h_grow.observe(s_old / s_new)
-                            self._h_scale[k].observe(s_new)
-                self._c_growths.inc(grown)
-                self._scales_host = new
-        t_chunk = self.obs.rebase(t1)
-        for slot in runnable:
-            b = slot.index
-            n = int(rem_dispatch[b] - rem_after[b])
-            if n:
-                slot.tokens.extend(buf[b, :n].tolist())
-                slot.pos = int(self._pos[b])
-                self._ctr["tokens"].inc(n)
-                if self.obs.enabled:
-                    # live-length bytes/token: what attention actually
-                    # streamed for this slot (worst case is in stats())
-                    self._h_attn_bytes.observe(
-                        self._attn_per_pos * int(self._pos[b]))
-                    tr = self._traces.get(slot.order)
-                    if tr is not None:
-                        tr.mark_chunk(t_chunk, n)
-            if anom[b]:
-                self._c_anom.inc()
-                self._finish(slot, FAILED)
-            elif done[b]:
-                self._finish(slot)
+        with self.obs.span("engine.decode.fetch"):
+            buf = np.asarray(buf)
+            self._cur = np.array(cur)
+            self._pos = np.array(pos)
+            rem_after = np.array(rem)
+            done = np.asarray(done)
+            anom = np.asarray(anom)
+            self._c_d2h.inc(6)
+        with self.obs.span("engine.decode.emit"):
+            self.obs.profiler.on_dispatch(loop_cost, self.obs.rebase(t0),
+                                          self.obs.rebase(t1))
+            saved = {s.index: self._rem[s.index] for s in stalled}
+            self._rem = rem_after
+            for idx, v in saved.items():
+                self._rem[idx] = v
+            dt = t1 - t0
+            self._ctr["decode_s"].inc(dt)
+            self._ctr["dispatches"].inc()
+            if self.obs.enabled:
+                self._h_chunk.observe(dt)
+                self._h_occup.observe(len(runnable) / max(self.max_slots, 1))
+                if self._health is not None and dstats is not None:
+                    # steps[b] = tokens slot b advanced this dispatch:
+                    # rows with 0 still carry init sentinels (or stale
+                    # maxima from the donated carry); the fold skips them
+                    with self.obs.span("health.fold"):
+                        self._c_d2h.inc()
+                        self._health.on_decode(np.asarray(dstats),
+                                               steps=rem_dispatch - rem_after)
+                if self._scales_host is not None:
+                    new = kvc.pool_scale_map(self.pool)
+                    self._c_d2h.inc(self._scale_leaves)
+                    grown = 0
+                    for k, old in self._scales_host.items():
+                        g = new[k] > old
+                        if g.any():
+                            grown += int(g.sum())
+                            ns, olds = new[k][g], old[g]
+                            # per-element round-off of a rescale is bounded
+                            # by new_scale/2; accumulate the per-group bound
+                            self._c_requant.inc(float(0.5 * ns.sum()))
+                            for s_old, s_new in zip(olds.tolist(),
+                                                    ns.tolist()):
+                                if s_new > 0:
+                                    self._h_grow.observe(s_old / s_new)
+                                self._h_scale[k].observe(s_new)
+                    self._c_growths.inc(grown)
+                    self._scales_host = new
+            t_chunk = self.obs.rebase(t1)
+            for slot in runnable:
+                b = slot.index
+                n = int(rem_dispatch[b] - rem_after[b])
+                if n:
+                    slot.tokens.extend(buf[b, :n].tolist())
+                    slot.pos = int(self._pos[b])
+                    self._ctr["tokens"].inc(n)
+                    if self.obs.enabled:
+                        # live-length bytes/token: what attention actually
+                        # streamed for this slot (worst case is in stats())
+                        self._h_attn_bytes.observe(
+                            self._attn_per_pos * int(self._pos[b]))
+                        tr = self._traces.get(slot.order)
+                        if tr is not None:
+                            tr.mark_chunk(t_chunk, n)
+                if anom[b]:
+                    self._c_anom.inc()
+                    self._finish(slot, FAILED)
+                elif done[b]:
+                    self._finish(slot)
 
     # -- terminal transitions ---------------------------------------------
     def _finish(self, slot, status: Optional[str] = None) -> None:
         """Retire a slot-resident request.  ``status`` None infers the
         natural finish (EOS vs budget); explicit statuses come from the
         cancel/timeout/failure paths."""
+        with self.obs.span("sched.retire"):
+            self._retire(slot, status)
+
+    def _retire(self, slot, status: Optional[str]) -> None:
         if status is None:
             toks = slot.tokens
             status = (FINISHED_EOS
