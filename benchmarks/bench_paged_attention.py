@@ -37,14 +37,15 @@ from repro.layers.attention import chunked_attention
 
 
 def make_case(*, slots, max_seq, page, Hkv, G, D, live_len, seed=0):
-    """Random pool sized for ``slots`` full reservations; every slot owns
-    its worst case (the scheduler's up-front reservation) but only
-    ``live_len`` positions are live — the oversubscribed-decode shape."""
+    """Random one-layer stacked pool sized for ``slots`` full reservations;
+    every slot owns its worst case (the scheduler's up-front reservation)
+    but only ``live_len`` positions are live — the oversubscribed-decode
+    shape."""
     rng = np.random.RandomState(seed)
     maxp = -(-max_seq // page)
     num_pages = slots * maxp + 1                  # + trash page 0
-    pool_k = rng.randn(num_pages, page, Hkv, D).astype(np.float32)
-    pool_v = rng.randn(num_pages, page, Hkv, D).astype(np.float32)
+    pool_k = rng.randn(1, num_pages, page, Hkv, D).astype(np.float32)
+    pool_v = rng.randn(1, num_pages, page, Hkv, D).astype(np.float32)
     free = list(range(1, num_pages))
     rng.shuffle(free)
     table = np.zeros((slots, maxp), np.int32)
@@ -60,11 +61,12 @@ def make_case(*, slots, max_seq, page, Hkv, G, D, live_len, seed=0):
 def step_fn(impl: str):
     if impl == "stream":
         def f(q, pool_k, pool_v, table, positions):
-            return kops.paged_attention(q, pool_k, pool_v, table, positions)
+            return kops.paged_attention(q, pool_k, pool_v, table, positions,
+                                        0)
     else:
         def f(q, pool_k, pool_v, table, positions):
-            k = kops.paged_gather(pool_k, table)
-            v = kops.paged_gather(pool_v, table)
+            k = kops.paged_gather(pool_k, table, 0)
+            v = kops.paged_gather(pool_v, table, 0)
             idx = jnp.arange(k.shape[1])[None, :]
             kvp = jnp.where(idx <= positions[:, None], idx, -1)
             o = chunked_attention(q[:, None], k, v,

@@ -117,7 +117,8 @@ def check_results(results, cfg, what: str) -> None:
 
 def kernel_check(cfg, seed: int) -> None:
     """The compiled paged-attention kernel against the XLA stream lowering
-    on one random pool at qwen3-4b's attention widths."""
+    on one random pool at qwen3-4b's attention widths, read at layer 1 of
+    a 2-layer stack."""
     from repro.kernels import ops as kops
     from repro.kernels.paged_attention import paged_attention_stream
     check(kops.kernel_mode() == "tpu", "kernel_mode() is not 'tpu' on a TPU")
@@ -130,19 +131,22 @@ def kernel_check(cfg, seed: int) -> None:
     table = rng.permutation(np.arange(1, P)).reshape(B, maxp).astype(np.int32)
     table[-1] = 0
     q = rng.randn(B, a.num_heads, a.head_dim).astype(np.float32)
-    shape = (P, PAGE, a.num_kv_heads, a.head_dim)
+    layers, layer = 2, 1
+    shape = (layers, P, PAGE, a.num_kv_heads, a.head_dim)
+    sshape = (layers, P, a.num_kv_heads)
     lanes = {
         "f32": (rng.randn(*shape).astype(np.float32),
                 rng.randn(*shape).astype(np.float32), {}),
         "int8": (rng.randint(-127, 128, shape).astype(np.int8),
                  rng.randint(-127, 128, shape).astype(np.int8),
-                 {"k_scale": rng.uniform(0.5, 1.5, (P, a.num_kv_heads))
+                 {"k_scale": rng.uniform(0.5, 1.5, sshape)
                   .astype(np.float32) / 127,
-                  "v_scale": rng.uniform(0.5, 1.5, (P, a.num_kv_heads))
+                  "v_scale": rng.uniform(0.5, 1.5, sshape)
                   .astype(np.float32) / 127}),
     }
     for lane, (pk, pv, scales) in lanes.items():
         args = [jnp.asarray(x) for x in (q, pk, pv, table, pos)]
+        args.append(jnp.int32(layer))
         sc = {k: jnp.asarray(v) for k, v in scales.items()}
         fn = jax.jit(functools.partial(kops.paged_attention, **sc))
         compiled = fn.lower(*args).compile()

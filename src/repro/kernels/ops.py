@@ -51,31 +51,33 @@ def bc_linear_fused(x, w, n_out: int, mode: str | None = None, **block_kw):
                                        **block_kw)
 
 
-def paged_gather(pool, table, mode: str | None = None):
-    """Gather a slot-contiguous KV view out of a paged pool.
+def paged_gather(pool, table, layer, mode: str | None = None):
+    """Gather a slot-contiguous KV view of one layer out of a paged pool.
 
-    pool: (P, page, H, D); table: (B, maxp) int32 page ids ->
-    (B, maxp * page, H, D).  'off' lowers through a plain XLA gather
-    (``pool[table]``); kernel modes run the scalar-prefetch Pallas gather.
+    pool: the stacked (n, P, page, H, D) leaf; table: (B, maxp) int32 page
+    ids; layer: int32 scalar stack index -> (B, maxp * page, H, D).  'off'
+    lowers through a plain XLA gather (``pool[layer, table]``); kernel
+    modes run the scalar-prefetch Pallas gather.
     """
     mode = mode or kernel_mode()
     if mode == "off":
-        _, page, H, D = pool.shape
+        _, _, page, H, D = pool.shape
         B, maxp = table.shape
-        return pool[table].reshape(B, maxp * page, H, D)
-    return _paged.paged_gather_kernel(pool, table,
+        return pool[layer, table].reshape(B, maxp * page, H, D)
+    return _paged.paged_gather_kernel(pool, table, layer,
                                       interpret=(mode == "interpret"))
 
 
-def paged_attention(q, pool_k, pool_v, table, positions, *, scale=None,
-                    softcap=0.0, k_scale=None, v_scale=None,
+def paged_attention(q, pool_k, pool_v, table, positions, layer, *,
+                    scale=None, softcap=0.0, k_scale=None, v_scale=None,
                     mode: str | None = None):
     """Fused paged flash-decode: stream pool pages through online-softmax.
 
-    q: (B, Hq, D) one decode query per slot; pool: (P, page, Hkv, D);
-    table: (B, maxp) int32 page ids; positions: (B,) int32 per-slot
-    absolute position of the decode token (-1 = idle, fully masked; the
-    output row is exactly zero) -> (B, Hq, D).
+    q: (B, Hq, D) one decode query per slot; pool: the stacked
+    (n, P, page, Hkv, D) leaf, read in place at ``(layer, page)``; table:
+    (B, maxp) int32 page ids; positions: (B,) int32 per-slot absolute
+    position of the decode token (-1 = idle, fully masked; the output row
+    is exactly zero); layer: int32 scalar stack index -> (B, Hq, D).
 
     The gathered ``(B, maxp * page, Hkv, D)`` KV view of the old
     ``paged_gather`` + dense-attention path is never formed: 'off' lowers a
@@ -84,7 +86,7 @@ def paged_attention(q, pool_k, pool_v, table, positions, *, scale=None,
     not reverse-differentiable); kernel modes run the scalar-prefetch
     Pallas flash-decode kernel (kernels/paged_attention.py).
 
-    ``k_scale``/``v_scale`` ((P, Hkv) f32, both or neither) select the
+    ``k_scale``/``v_scale`` ((n, P, Hkv) f32, both or neither) select the
     QUANTIZED lane: the pool leaves are int8 (repro.quant) and every
     lowering dequantizes page chunks in-register beside the m/l/acc carry
     — attention HBM traffic is measured in int8 bytes.
@@ -92,11 +94,11 @@ def paged_attention(q, pool_k, pool_v, table, positions, *, scale=None,
     mode = mode or kernel_mode()
     if mode == "off":
         return _pa.paged_attention_stream(q, pool_k, pool_v, table,
-                                          positions, scale=scale,
+                                          positions, layer, scale=scale,
                                           softcap=softcap,
                                           k_scale=k_scale, v_scale=v_scale)
     return _pa.paged_attention_kernel(q, pool_k, pool_v, table, positions,
-                                      scale=scale, softcap=softcap,
+                                      layer, scale=scale, softcap=softcap,
                                       k_scale=k_scale, v_scale=v_scale,
                                       interpret=(mode == "interpret"))
 

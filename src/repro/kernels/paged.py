@@ -34,22 +34,26 @@ def _gather_kernel(table_ref, pool_ref, out_ref):
     out_ref[...] = pool_ref[...].reshape(out_ref.shape)
 
 
-def paged_gather_kernel(pool: jax.Array, table: jax.Array,
+def paged_gather_kernel(pool: jax.Array, table: jax.Array, layer,
                         interpret: bool = False) -> jax.Array:
-    """pool: (P, page, H, D); table: (B, maxp) int32 page ids.
+    """pool: the stacked (n, P, page, H, D) leaf; table: (B, maxp) int32
+    page ids; layer: int32 scalar stack index.
 
-    Returns (B, maxp * page, H, D): slot b's pages concatenated in table
-    order (position ``i`` of slot b lives at page ``table[b, i // page]``,
-    offset ``i % page``).
+    Returns (B, maxp * page, H, D): slot b's pages of that layer
+    concatenated in table order (position ``i`` of slot b lives at page
+    ``table[b, i // page]``, offset ``i % page``).  The layer rides inside
+    the prefetched table (entries ``layer * P + page``, split back by the
+    index map), as in the paged attention kernel.
     """
-    P, page, H, D = pool.shape
+    _, P, page, H, D = pool.shape
     B, maxp = table.shape
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(B, maxp),
         in_specs=[
-            pl.BlockSpec((1, page, H, D),
-                         lambda b, p, tref: (tref[b, p], 0, 0, 0)),
+            pl.BlockSpec((pl.squeezed, 1, page, H, D),
+                         lambda b, p, tref: (tref[b, p] // P,
+                                             tref[b, p] % P, 0, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, page, H, D),
                                lambda b, p, tref: (b, p, 0, 0, 0)),
@@ -60,5 +64,5 @@ def paged_gather_kernel(pool: jax.Array, table: jax.Array,
         out_shape=jax.ShapeDtypeStruct((B, maxp, page, H, D), pool.dtype),
         interpret=interpret,
         name="paged_gather",
-    )(table, pool)
+    )(layer * P + table, pool)
     return out.reshape(B, maxp * page, H, D)

@@ -18,6 +18,13 @@ trash-page-0 reads (unowned table entries only appear beyond the length),
 the partially-filled last page, and idle slots (``positions == -1`` masks
 everything, so the output is exactly zero, as the gather path produced).
 
+Both lowerings read the pool where the serving stack keeps it: the whole
+stacked ``(n, P, page, Hkv, D)`` leaf of the layer scan, with the layer
+as an argument.  A decode step writes its rows into that leaf in place
+and reads its layer's pages out of it at ``(layer, page)``; no layer's
+``(P, page, Hkv, D)`` slab is ever sliced out, so the pool is never
+copied and the decode program holds it once.
+
 Two lowerings, dispatched by ``kernels.ops.paged_attention``:
 
 * ``paged_attention_stream`` — pure XLA: a live-length-bounded
@@ -25,11 +32,13 @@ Two lowerings, dispatched by ``kernels.ops.paged_attention``:
   each step; serving-only — a while loop is not reverse-differentiable).
   Same memory win under XLA alone; this is what every non-TPU backend
   (the CPU, and the 512-chip dry-run) lowers.
-* ``paged_attention_kernel`` — Pallas: the block table and per-slot
-  positions ride scalar prefetch (``PrefetchScalarGridSpec``), so each
-  grid step DMAs exactly one pool page straight into VMEM next to the
+* ``paged_attention_kernel`` — Pallas: the block table (its entries
+  ``layer * P + page``) and per-slot positions ride scalar prefetch
+  (``PrefetchScalarGridSpec``), so each grid step DMAs exactly one page
+  of one layer from the pool in HBM straight into VMEM next to the
   running softmax state — the paper's hierarchical-control split with the
-  data plane never leaving on-chip memory.
+  data plane never leaving on-chip memory.  It takes ``(table, positions,
+  q, pool K, pool V[, K scales, V scales])``.
 """
 from __future__ import annotations
 
@@ -51,15 +60,18 @@ BLOCK_PAGES = 4
 # ---------------------------------------------------------------------------
 # Pure-XLA streamed lowering ('off' dispatch)
 # ---------------------------------------------------------------------------
-def paged_attention_stream(q, pool_k, pool_v, table, positions, *,
+def paged_attention_stream(q, pool_k, pool_v, table, positions, layer, *,
                            scale=None, softcap: float = 0.0,
                            block_pages: int = BLOCK_PAGES,
                            k_scale=None, v_scale=None) -> jax.Array:
-    """q: (B, Hq, D); pool: (P, page, Hkv, D); table: (B, maxp) int32 page
-    ids; positions: (B,) int32 per-slot absolute position of the decode
-    token (-1 = idle slot, fully masked).  Returns (B, Hq, D) in q.dtype.
+    """q: (B, Hq, D); pool: the stacked (n, P, page, Hkv, D) leaf; table:
+    (B, maxp) int32 page ids; positions: (B,) int32 per-slot absolute
+    position of the decode token (-1 = idle slot, fully masked); layer:
+    int32 scalar, the stack index whose pages are read (pages are gathered
+    at ``(layer, page)``: the layer's slab is never sliced out).  Returns
+    (B, Hq, D) in q.dtype.
 
-    ``k_scale``/``v_scale`` (both (P, Hkv) f32, or both None) enable the
+    ``k_scale``/``v_scale`` (both (n, P, Hkv) f32, or both None) enable the
     quantized lane: the pool leaves are int8 and each streamed page chunk
     is dequantized IN-REGISTER right next to the m/l/acc carry — HBM
     traffic stays int8 bytes, the softmax recurrence stays f32.
@@ -72,7 +84,7 @@ def paged_attention_stream(q, pool_k, pool_v, table, positions, *,
     O(max_seq).  ``block_pages`` pages stream per step: enough MXU/AVX work
     per iteration to amortize loop overhead, still an O(page) working set.
     """
-    _, page, Hkv, D = pool_k.shape
+    _, _, page, Hkv, D = pool_k.shape
     B, maxp = table.shape
     Hq = q.shape[1]
     G = Hq // Hkv
@@ -95,11 +107,11 @@ def paged_attention_stream(q, pool_k, pool_v, table, positions, *,
     def body(st):
         j, m_p, l_p, acc = st
         pids = jax.lax.dynamic_slice_in_dim(table, j * bp, bp, 1)  # (B, bp)
-        kc = pool_k[pids].astype(jnp.float32)    # (B, bp, page, Hkv, D)
-        vc = pool_v[pids].astype(jnp.float32)
+        kc = pool_k[layer, pids].astype(jnp.float32)  # (B,bp,page,Hkv,D)
+        vc = pool_v[layer, pids].astype(jnp.float32)
         if k_scale is not None:                  # int8 lane: dequantize the
-            kc = kc * k_scale[pids][:, :, None, :, None]   # chunk in-register
-            vc = vc * v_scale[pids][:, :, None, :, None]
+            kc = kc * k_scale[layer, pids][:, :, None, :, None]  # in-register
+            vc = vc * v_scale[layer, pids][:, :, None, :, None]
         kc = kc.reshape(B, bp * page, Hkv, D)
         vc = vc.reshape(B, bp * page, Hkv, D)
         s = jnp.einsum("bhgd,bkhd->bhgk", qh, kc)
@@ -199,42 +211,50 @@ def _pa_kernel(table_ref, pos_ref, q_ref, k_ref, v_ref, *refs,
         o_ref[0] = (acc_scr[...] / l).astype(o_ref.dtype)
 
 
-def paged_attention_kernel(q, pool_k, pool_v, table, positions, *,
+def paged_attention_kernel(q, pool_k, pool_v, table, positions, layer, *,
                            scale=None, softcap: float = 0.0,
                            interpret: bool = False,
                            k_scale=None, v_scale=None) -> jax.Array:
     """Same contract as ``paged_attention_stream``; grid (B, maxp) with the
     page dim sequential, block table + positions scalar-prefetched so the
-    page id is known before each step's pool DMA issues.  Each step moves
-    one whole page, ``(1, page, Hkv, D)``: its last two dims are the pool's
-    own, which is what the TPU's block-shape rule asks for in every dtype.
-    With ``k_scale``/``v_scale`` ((P, Hkv) f32) the pool is int8: the page
-    DMA moves int8 bytes, and the page's ``(1, 1, Hkv)`` scale row rides
-    along and dequantizes in VMEM."""
-    _, page, Hkv, D = pool_k.shape
+    page id is known before each step's pool DMA issues.
+
+    The kernel takes the whole stacked pool leaf, which stays in HBM and is
+    read in place.  The layer rides inside the prefetched table: its
+    entries are ``layer * P + page``, and the index map splits each back
+    into ``(layer, page)``, so the operands stay ``(table, positions, q,
+    pool K, pool V[, K scales, V scales])``.  Each step moves one whole
+    page, ``(page, Hkv, D)`` of one layer: its last two dims are the
+    pool's own, which is what the TPU's block-shape rule asks for in every
+    dtype.  With ``k_scale``/``v_scale`` ((n, P, Hkv) f32) the pool is
+    int8: the page DMA moves int8 bytes, and the page's ``(1, Hkv)`` scale
+    row rides along and dequantizes in VMEM."""
+    _, P, page, Hkv, D = pool_k.shape
     B, maxp = table.shape
     Hq = q.shape[1]
     scale = scale if scale is not None else D ** -0.5
     quantized = k_scale is not None
+    entries = layer * P + table                  # (layer, page) in one int
 
     def page_of(b, jp, tref, pref):
         # dead steps repeat the slot's last live page (no new DMA)
         last = jnp.maximum(pref[b], 0) // page
-        return tref[b, jnp.minimum(jp, last)]
+        e = tref[b, jnp.minimum(jp, last)]
+        return e // P, e % P
 
     pool_spec = pl.BlockSpec(
-        (1, page, Hkv, D),
-        lambda b, jp, tref, pref: (page_of(b, jp, tref, pref), 0, 0, 0))
+        (pl.squeezed, 1, page, Hkv, D),
+        lambda b, jp, tref, pref: (*page_of(b, jp, tref, pref), 0, 0, 0))
     row_spec = pl.BlockSpec((1, Hq, D),
                             lambda b, jp, tref, pref: (b, 0, 0))
     in_specs = [row_spec, pool_spec, pool_spec]
     operands = [q, pool_k, pool_v]
     if quantized:
         scale_spec = pl.BlockSpec(
-            (1, 1, Hkv),
-            lambda b, jp, tref, pref: (page_of(b, jp, tref, pref), 0, 0))
+            (pl.squeezed, 1, 1, Hkv),
+            lambda b, jp, tref, pref: (*page_of(b, jp, tref, pref), 0, 0))
         in_specs += [scale_spec, scale_spec]
-        operands += [k_scale[:, None, :], v_scale[:, None, :]]
+        operands += [k_scale[:, :, None, :], v_scale[:, :, None, :]]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,                   # (table, positions)
@@ -258,4 +278,4 @@ def paged_attention_kernel(q, pool_k, pool_v, table, positions, *,
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
         name="paged_attention",
-    )(table, positions, *operands)
+    )(entries, positions, *operands)

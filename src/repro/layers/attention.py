@@ -179,7 +179,7 @@ def attention_block(params, x, *, cfg, causal=True, window=0,
                     positions=None, cache=None, cache_pos=None,
                     cross_kv=None, mode="train", impl="chunked",
                     q_chunk=1024, kv_chunk=1024,
-                    block_table=None,
+                    block_table=None, layer=None,
                     paged_impl="stream") -> Tuple[jax.Array, Optional[Dict]]:
     """Full attention block.  Returns (out, updated_cache).
 
@@ -187,13 +187,17 @@ def attention_block(params, x, *, cfg, causal=True, window=0,
     cache_pos: scalar absolute position of the first new token (decode).
     cross_kv: precomputed (k, v) from the encoder (cross-attention).
 
-    Paged decode (``block_table`` set): cache is a page POOL
-    {"k": (P, page, Hkv, D), "v": ...} shared by every slot;
-    ``block_table`` (B, maxp) maps slot positions onto pages and
+    Paged decode (``block_table`` set): cache is the stacked page POOL
+    {"k": (n, P, page, Hkv, D), "v": ...} shared by every slot and every
+    layer of the scan, and ``layer`` (int32 scalar) is this block's index
+    in it; ``block_table`` (B, maxp) maps slot positions onto pages and
     ``cache_pos`` is per-slot (B,) — position ``i`` of slot ``b`` lives at
-    page ``block_table[b, i // page]``, offset ``i % page``.  A slot with
-    ``cache_pos == -1`` is idle: its write routes to the reserved trash
-    page 0 and its attention is fully masked (output discarded upstream).
+    ``(layer, block_table[b, i // page], i % page)``.  The new K/V row is
+    written there in place and attention reads the layer's pages out of
+    the stacked leaf: the layer's slab is never sliced out, so the pool is
+    updated in place and held once.  A slot with ``cache_pos == -1`` is
+    idle: its write routes to the reserved trash page 0 and its attention
+    is fully masked (output discarded upstream).
 
     ``paged_impl`` picks the paged attention lowering: "stream" (default)
     runs the fused paged flash-decode (``kernels.ops.paged_attention`` —
@@ -264,7 +268,7 @@ def attention_block(params, x, *, cfg, causal=True, window=0,
         assert not window, "paged KV path serves linear caches only"
         pool_k, pool_v = cache["k"], cache["v"]
         k_sc = v_sc = None
-        page = pool_k.shape[1]
+        page = pool_k.shape[2]
         maxp = block_table.shape[1]
         col = jnp.minimum(q_pos0 // page, maxp - 1)
         pid = jnp.where(cache_pos >= 0,
@@ -276,15 +280,15 @@ def attention_block(params, x, *, cfg, causal=True, window=0,
                 # scatter, requantize-on-grow
                 from ..quant import codec as qcodec
                 pool_k, k_sc = qcodec.page_scatter(
-                    pool_k, cache["k_scale"], pid, off, k[:, 0])
+                    pool_k, cache["k_scale"], layer, pid, off, k[:, 0])
                 pool_v, v_sc = qcodec.page_scatter(
-                    pool_v, cache["v_scale"], pid, off, v[:, 0])
+                    pool_v, cache["v_scale"], layer, pid, off, v[:, 0])
                 new_cache = {"k": pool_k, "v": pool_v,
                              "k_scale": k_sc, "v_scale": v_sc}
             else:
-                pool_k = pool_k.at[pid, off].set(
+                pool_k = pool_k.at[layer, pid, off].set(
                     k[:, 0].astype(pool_k.dtype))
-                pool_v = pool_v.at[pid, off].set(
+                pool_v = pool_v.at[layer, pid, off].set(
                     v[:, 0].astype(pool_v.dtype))
                 new_cache = {"k": pool_k, "v": pool_v}
         if paged_impl == "stream":
@@ -295,14 +299,14 @@ def attention_block(params, x, *, cfg, causal=True, window=0,
             # rows the masked gather path produced.
             qd = shard_heads(q[:, 0])
             streamed = shard_heads(kops.paged_attention(
-                qd, pool_k, pool_v, block_table, cache_pos,
+                qd, pool_k, pool_v, block_table, cache_pos, layer,
                 softcap=a.logit_softcap, k_scale=k_sc, v_scale=v_sc))[:, None]
         else:
-            k = kops.paged_gather(pool_k, block_table)
-            v = kops.paged_gather(pool_v, block_table)
+            k = kops.paged_gather(pool_k, block_table, layer)
+            v = kops.paged_gather(pool_v, block_table, layer)
             if k_sc is not None:                 # dequantize the gathered
                 rep = lambda s: jnp.repeat(     # view: page scales repeat
-                    s[block_table], page, axis=1)[..., None]  # per offset
+                    s[layer, block_table], page, axis=1)[..., None]
                 k = k.astype(jnp.float32) * rep(k_sc)
                 v = v.astype(jnp.float32) * rep(v_sc)
             idx = jnp.arange(k.shape[1])[None, :]

@@ -105,8 +105,9 @@ def init_block(key, kind: str, cfg: ArchConfig) -> Dict:
 
 def apply_block(params, x, kind: str, cfg: ArchConfig, *, mode: str,
                 cache=None, cache_pos=None, q_chunk: int, kv_chunk: int,
-                block_table=None, paged_impl: str = "stream"):
-    """Returns (x, new_cache, aux)."""
+                block_table=None, layer=None, paged_impl: str = "stream"):
+    """Returns (x, new_cache, aux).  Paged decode passes the block's whole
+    stacked pool leaf as ``cache`` and its stack index as ``layer``."""
     comp = cfg.compression
     aux = jnp.zeros((), jnp.float32)
     new_cache = cache
@@ -117,7 +118,7 @@ def apply_block(params, x, kind: str, cfg: ArchConfig, *, mode: str,
                 params["attn"], h, cfg=cfg, causal=True,
                 window=_window_for(kind, cfg), cache=cache,
                 cache_pos=cache_pos, mode=mode, q_chunk=q_chunk,
-                kv_chunk=kv_chunk, block_table=block_table,
+                kv_chunk=kv_chunk, block_table=block_table, layer=layer,
                 paged_impl=paged_impl)
             if "ln1_post" in params:
                 a = norm_lib.apply_norm(cfg.norm, params["ln1_post"], a)
@@ -226,9 +227,14 @@ def forward(params, tokens, cfg: ArchConfig, *, mode: str = "train",
 
     With ``block_table`` set, ``cache`` is a paged pool tree (attention
     leaves {"k","v"} shaped (n, P, page, Hkv, D)) and ``cache_pos`` is the
-    per-slot (B,) position vector — see serve/kvcache.py.  ``paged_impl``
-    selects the paged attention lowering ("stream" fused flash-decode /
-    "gather" legacy materialized view — see layers/attention.py).
+    per-slot (B,) position vector — see serve/kvcache.py.  The pool then
+    rides the layer scan's CARRY, not its xs/ys: each group gets its index
+    (``jnp.arange(n)`` as xs, a python int when unrolled) and writes and
+    reads its pages of the stacked leaves in place, so XLA neither slices a
+    layer's slab out of the stack nor restacks it, and the pool is held
+    once.  ``paged_impl`` selects the paged attention lowering ("stream"
+    fused flash-decode / "gather" legacy materialized view — see
+    layers/attention.py).
     """
     q_chunk = q_chunk or cfg.attn_q_chunk
     kv_chunk = kv_chunk or cfg.attn_kv_chunk
@@ -259,9 +265,7 @@ def forward(params, tokens, cfg: ArchConfig, *, mode: str = "train",
             seg_params = params["segments"][si]
             seg_cache = None if cache is None else cache[si]
 
-            def group_fn(carry, xs):
-                x_, aux_ = carry
-                gp, gc = xs
+            def run_group(x_, aux_, gp, gc, layer):
                 new_gc = []
                 for bi, kind in enumerate(pattern):
                     bp = gp[bi]
@@ -271,16 +275,26 @@ def forward(params, tokens, cfg: ArchConfig, *, mode: str = "train",
                         bp, x_, kind, cfg, mode=mode, cache=c_in,
                         cache_pos=cache_pos, q_chunk=q_chunk,
                         kv_chunk=kv_chunk, block_table=block_table,
-                        paged_impl=paged_impl)
+                        layer=layer, paged_impl=paged_impl)
                     new_gc.append(c_out)
                     aux_ = aux_ + aux_b
                 x_ = shard_act(x_)
-                new_gc = tuple(new_gc) if gc is not None else 0
+                return x_, aux_, (tuple(new_gc) if gc is not None else 0)
+
+            def group_fn(carry, xs):
+                gp, gc = xs
+                x_, aux_, new_gc = run_group(*carry, gp, gc, None)
                 return (x_, aux_), new_gc
+
+            def paged_group_fn(carry, xs):
+                x_, aux_, pool_ = carry
+                gp, layer = xs
+                return run_group(x_, aux_, gp, pool_, layer), None
 
             if cfg.remat == "full" and mode == "train":
                 group_fn = jax.checkpoint(
                     group_fn, policy=jax.checkpoint_policies.nothing_saveable)
+            paged = block_table is not None and seg_cache is not None
             if cfg.unroll_scan:
                 # python loop over groups: exact cost_analysis / collective
                 # counts for the roofline lowering (a while body is costed
@@ -288,13 +302,22 @@ def forward(params, tokens, cfg: ArchConfig, *, mode: str = "train",
                 outs = []
                 for g in range(n):
                     gp = jax.tree.map(lambda a: a[g], seg_params)
-                    gc = (None if seg_cache is None else
-                          jax.tree.map(lambda a: a[g], seg_cache))
-                    (x, aux_total), new_gc = group_fn((x, aux_total),
-                                                      (gp, gc))
-                    outs.append(new_gc)
+                    if paged:                   # the same carried pool
+                        x, aux_total, seg_cache = run_group(
+                            x, aux_total, gp, seg_cache, g)
+                    else:
+                        gc = (None if seg_cache is None else
+                              jax.tree.map(lambda a: a[g], seg_cache))
+                        (x, aux_total), new_gc = group_fn((x, aux_total),
+                                                          (gp, gc))
+                        outs.append(new_gc)
                 new_seg_cache = (jax.tree.map(lambda *a: jnp.stack(a), *outs)
-                                if seg_cache is not None else None)
+                                 if seg_cache is not None and not paged
+                                 else seg_cache)
+            elif paged:
+                (x, aux_total, new_seg_cache), _ = jax.lax.scan(
+                    paged_group_fn, (x, aux_total, seg_cache),
+                    (seg_params, jnp.arange(n)))
             elif seg_cache is not None:
                 (x, aux_total), new_seg_cache = jax.lax.scan(
                     group_fn, (x, aux_total), (seg_params, seg_cache))

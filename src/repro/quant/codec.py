@@ -13,8 +13,9 @@ fixed-point layer for the serving stack:
   output block ``p``, the granularity one accelerator PE column owns), so
   the serve-mode contraction reads int8 planes and folds the f32 scale
   into the output once per row: ``y[..., p, f] = s[p] * (x . q[p])``.
-* **Paged KV pool** — the ``(num_pages, page_size, Hkv, D)`` pool of
-  serve/kvcache.py stores int8 with one scale per (page, kv-head).  Pages
+* **Paged KV pool** — the stacked ``(n, num_pages, page_size, Hkv, D)``
+  pool of serve/kvcache.py stores int8 with one scale per (layer, page,
+  kv-head).  Pages
   fill incrementally (one decode token at a time), so the page scale is a
   RUNNING absmax: when a new token's magnitude exceeds the page's scale,
   the resident int8 entries are rescaled in-register to the grown scale
@@ -298,14 +299,16 @@ def quantize_page_block(vals: jax.Array) -> Tuple[jax.Array, jax.Array]:
     return q, scale.astype(jnp.float32)
 
 
-def page_scatter(pool_q: jax.Array, scales: jax.Array, pid: jax.Array,
-                 off: jax.Array, x: jax.Array
+def page_scatter(pool_q: jax.Array, scales: jax.Array, layer,
+                 pid: jax.Array, off: jax.Array, x: jax.Array
                  ) -> Tuple[jax.Array, jax.Array]:
     """Decode-path write of one token per slot into an int8 page pool.
 
-    pool_q: (P, page, H, D) int8;  scales: (P, H) f32;  pid/off: (B,)
-    int32 page id / in-page offset per slot;  x: (B, H, D) new K or V
-    rows.  Returns the updated (pool_q, scales).
+    pool_q: the stacked (n, P, page, H, D) int8 leaf;  scales: (n, P, H)
+    f32;  layer: int32 scalar stack index;  pid/off: (B,) int32 page id /
+    in-page offset per slot;  x: (B, H, D) new K or V rows.  Writes land
+    at ``(layer, pid, off)`` of the stacked leaves in place.  Returns the
+    updated (pool_q, scales).
 
     Per-page scales must stay valid for values ALREADY in the page, so the
     scale only ever grows: ``s_new = max(s_old, absmax(x)/127)`` per head,
@@ -331,25 +334,27 @@ def page_scatter(pool_q: jax.Array, scales: jax.Array, pid: jax.Array,
     leaves around decode dispatches into the ``quant.scale_growths``
     counter (docs/observability.md).
     """
-    page = pool_q.shape[1]
-    s_old = jnp.where((off == 0)[:, None], 0.0, scales[pid])   # (B, H)
+    page = pool_q.shape[2]
+    s_old = jnp.where((off == 0)[:, None], 0.0,
+                      scales[layer, pid])                      # (B, H)
     s_new = jnp.maximum(s_old, absmax_scale(x, axes=-1))       # (B, H)
 
     def requant(carry):
         pq, sc = carry
         ratio = s_old / jnp.maximum(s_new, _EPS)               # <= 1
-        resident = pq[pid]                                     # (B,page,H,D)
+        resident = pq[layer, pid]                              # (B,page,H,D)
         resident = jnp.round(resident.astype(jnp.float32)
                              * ratio[:, None, :, None]).astype(jnp.int8)
         tok = quantize(x, s_new[..., None])                    # (B, H, D)
         hit = (jnp.arange(page)[None, :] == off[:, None])      # (B, page)
         resident = jnp.where(hit[..., None, None], tok[:, None], resident)
-        return pq.at[pid].set(resident), sc.at[pid].set(s_new)
+        return (pq.at[layer, pid].set(resident),
+                sc.at[layer, pid].set(s_new))
 
     def fast(carry):
         pq, sc = carry                                         # s_new == s_old
-        return (pq.at[pid, off].set(quantize(x, s_new[..., None])),
-                sc.at[pid].set(s_new))
+        return (pq.at[layer, pid, off].set(quantize(x, s_new[..., None])),
+                sc.at[layer, pid].set(s_new))
 
     return jax.lax.cond(jnp.any(s_new > s_old), requant, fast,
                         (pool_q, scales))

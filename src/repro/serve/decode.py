@@ -277,6 +277,11 @@ def make_paged_decode_loop(cfg: ArchConfig, chunk: int, *,
     stream through online-softmax, so the loop's peak memory no longer
     carries a ``(B, maxp * page, Hkv, D)`` gathered KV view per layer;
     "gather" keeps the PR 3 materialized-view path as the parity oracle.
+    Either way the stacked pool rides this loop's carry and, inside each
+    step, the layer scan's carry (models/transformer.py): layers write and
+    read it at ``(layer, page)`` in place, so the carry stays aliased to
+    the donated pool and the program holds it once (the engine reports
+    the program's temporaries as ``engine.decode_temp_bytes``).
 
     With ``nan_guard`` (default) the step checks its last-position logits
     for NaN/Inf ON DEVICE (one ``isfinite`` reduce over the logit row —

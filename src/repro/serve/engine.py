@@ -494,6 +494,9 @@ class ContinuousEngine:
         self._c_anom = reg.counter("engine.anomalies")
         self._h_prefill = reg.histogram("engine.prefill_dispatch_s")
         self._h_chunk = reg.histogram("engine.decode_chunk_s")
+        # the decode program's temporaries, from its executable: the pool
+        # rides the program's carry in place, so this stays under one pool
+        self._g_temp = reg.gauge("engine.decode_temp_bytes")
         # host time of a step in which the device had nothing queued: the
         # step's span less its fences (engine.*.fence), one value per step
         # that dispatched a decode chunk
@@ -1019,6 +1022,8 @@ class ContinuousEngine:
                     self._loop_exec = aot_compile(
                         self._loop, args, self.obs.profiler,
                         dec.DECODE_CHUNK_KIND)
+                    self._g_temp.set(self._loop_exec[0].memory_analysis()
+                                     .temp_size_in_bytes)
             loop, loop_cost = self._loop_exec
             buf, cur, self.pool, pos, rem, done, anom, dstats = loop(*args)
         # fence before the span boundary: the decode_chunk wall time (and
@@ -1217,6 +1222,8 @@ class ContinuousEngine:
             st["shadow_oracle"] = self._shadow.stats()
         st["pool_bytes"] = kvc.pool_bytes(self.pool)
         st["kv_pool_bytes"] = st["pool_bytes"]     # quant-satellite alias
+        st["decode_temp_bytes"] = (int(v("engine.decode_temp_bytes"))
+                                   if self._loop_exec is not None else None)
         st["quant_policy"] = self.quant.describe()
         st["prefill_buckets"] = sorted(self._prefills)
         st["attention_impl"] = self.paged_attn

@@ -7,8 +7,12 @@ dense serving cache breaks that on the memory side: every request owns a
 ``(max_seq, Hkv, D)`` slab per layer until the *slowest* request in its
 batch finishes.  This module replaces the slab with vLLM-style paging:
 
-* the pool is one ``(num_pages, page_size, Hkv, D)`` tensor per attention
-  layer (stacked over scan groups like the dense cache it replaces),
+* the pool is one stacked ``(n, num_pages, page_size, Hkv, D)`` leaf per
+  attention block of the layer pattern (``n`` scan groups, like the dense
+  cache it replaces); decode indexes it by ``(layer, page)`` in place —
+  the leaf rides the layer scan's carry, each layer writes its row at
+  ``(layer, page, offset)`` and its attention reads ``(layer, page)``
+  blocks — so no layer's slab is sliced out and the pool is held once,
 * a request owns an ordered list of page ids; position ``i`` lives at page
   ``table[i // page_size]``, offset ``i % page_size``,
 * pages come from a host-side free list, are RESERVED up front for a
@@ -21,8 +25,8 @@ of idle/frozen decode slots (see layers/attention.py paged branch).
 
 Host bookkeeping (``PageAllocator`` / ``BlockTable``) is pure python so the
 scheduler invariants are hypothesis-testable without a device; the device
-pool is a plain pytree built by ``build_pool`` and threaded through the
-decode loop like the dense cache.
+pool is a plain pytree built by ``build_pool``, threaded through the
+decode loop's carry and donated at its jit boundary.
 """
 from __future__ import annotations
 
@@ -215,7 +219,8 @@ def build_pool(cfg: ArchConfig, num_pages: int, page_size: int,
     Every attention cache leaf ``{"k": (n, B, S, Hkv, D), "v": ..., "pos"}``
     becomes ``{"k": (n, num_pages, page_size, Hkv, D), "v": ...}`` — one
     shared pool per layer, indexed by the same block table at every layer
-    (a logical page id is valid for the whole stack).  The "pos" leaf is
+    (a logical page id is valid for the whole stack); decode reads and
+    writes it at ``(layer, page)`` without slicing the stack.  The "pos" leaf is
     dropped: validity is carried by the per-slot position vector.
 
     The storage dtype is a first-class ``QuantPolicy`` field

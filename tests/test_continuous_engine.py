@@ -67,6 +67,9 @@ def test_matches_oracle_with_recycling(arch_setup):
     st = eng.stats()
     assert st["pages_in_use"] == 0          # free list fully restored
     assert st["retired"] == len(reqs)
+    # the decode program's temporaries, read from its executable
+    assert isinstance(st["decode_temp_bytes"], int)
+    assert st["decode_temp_bytes"] > 0
 
 
 def test_matches_oracle_bf16_tinyllama():
@@ -159,6 +162,7 @@ def test_arrival_times_and_latency_fields(tiny_setup):
 def test_telemetry(tiny_setup):
     cfg, params = tiny_setup
     eng = ContinuousEngine(cfg, params, max_slots=2, max_seq=32, page_size=4)
+    assert eng.stats()["decode_temp_bytes"] is None   # nothing compiled yet
     eng.generate(_reqs([(12, 6), (8, 10), (16, 4)]))
     st = eng.stats()
     assert st["requests"] == st["retired"] == 3

@@ -232,13 +232,16 @@ def test_build_pool_rejects_unservable():
 
 
 def test_paged_gather_modes_agree():
+    """Both lowerings gather one layer's pages out of the stacked pool."""
     rng = np.random.RandomState(0)
-    pool = jnp.asarray(rng.randn(9, 4, 2, 8).astype(np.float32))
+    pool = jnp.asarray(rng.randn(3, 9, 4, 2, 8).astype(np.float32))
     table = jnp.asarray(rng.randint(0, 9, size=(3, 5)).astype(np.int32))
-    off = kops.paged_gather(pool, table, mode="off")
-    ref = np.asarray(pool)[np.asarray(table).reshape(-1)].reshape(3, 20, 2, 8)
+    layer = 2
+    off = kops.paged_gather(pool, table, layer, mode="off")
+    ref = np.asarray(pool)[layer][np.asarray(table).reshape(-1)].reshape(
+        3, 20, 2, 8)
     np.testing.assert_array_equal(np.asarray(off), ref)
-    interp = kops.paged_gather(pool, table, mode="interpret")
+    interp = kops.paged_gather(pool, table, layer, mode="interpret")
     np.testing.assert_array_equal(np.asarray(interp), ref)
 
 

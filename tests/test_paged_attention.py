@@ -3,6 +3,10 @@
 oracle, over random pools, unaligned lengths, idle (trash-page) slots, and
 GQA ratios — plus the engine-level stream/gather token identity and the
 decode head-sharding spec.
+
+Every lowering reads one layer of the stacked ``(n, P, page, Hkv, D)``
+pool in place: the cases put the pool under test at a layer other than 0
+of a stack whose other layers hold other values.
 """
 import jax
 import jax.numpy as jnp
@@ -14,7 +18,9 @@ from repro.configs.registry import get_smoke_config
 from repro.dist import sharding as sh
 from repro.kernels import ops as kops
 from repro.kernels import ref as kref
+from repro.layers import attention as attn_lib
 from repro.models.registry import build_model
+from repro.serve import kvcache as kvc
 from repro.serve.engine import ContinuousEngine, Engine, Request
 
 try:
@@ -49,11 +55,25 @@ def _pool_case(rng, *, num_pages, page, Hkv, G, D, positions, softcap=0.0):
             softcap)
 
 
+LAYER = 1                                  # the pool's index in its stack
+
+
+def _stacked(rng, pool, n=3, layer=LAYER):
+    """``pool`` at ``layer`` of an n-layer stack; the other layers random."""
+    stack = rng.randn(n, *pool.shape).astype(np.float32)
+    stack[layer] = np.asarray(pool)
+    return jnp.asarray(stack)
+
+
 def _oracle(q, pool_k, pool_v, table, positions, softcap):
-    """Gathered view + attention_ref, one slot at a time."""
-    gk = np.asarray(kops.paged_gather(pool_k, table, mode="off"))
-    gv = np.asarray(kops.paged_gather(pool_v, table, mode="off"))
+    """Gathered view (plain numpy indexing of one layer's own pool) +
+    attention_ref, one slot at a time."""
     B, Hq, D = q.shape
+    maxp = table.shape[1]
+    _, page, Hkv, _ = pool_k.shape
+    tab = np.asarray(table)
+    gk = np.asarray(pool_k)[tab].reshape(B, maxp * page, Hkv, D)
+    gv = np.asarray(pool_v)[tab].reshape(B, maxp * page, Hkv, D)
     out = np.zeros((B, Hq, D), np.float32)
     for b in range(int(B)):
         L = int(positions[b]) + 1
@@ -70,9 +90,11 @@ def _oracle(q, pool_k, pool_v, table, positions, softcap):
 def _check(case, tol=2e-5):
     q, pool_k, pool_v, table, positions, softcap = case
     want = _oracle(q, pool_k, pool_v, table, positions, softcap)
-    off = kops.paged_attention(q, pool_k, pool_v, table, positions,
+    rng = np.random.RandomState(7)
+    sk, sv = _stacked(rng, pool_k), _stacked(rng, pool_v)
+    off = kops.paged_attention(q, sk, sv, table, positions, LAYER,
                                softcap=softcap, mode="off")
-    interp = kops.paged_attention(q, pool_k, pool_v, table, positions,
+    interp = kops.paged_attention(q, sk, sv, table, positions, LAYER,
                                   softcap=softcap, mode="interpret")
     np.testing.assert_allclose(np.asarray(off), want, rtol=tol, atol=tol)
     np.testing.assert_allclose(np.asarray(interp), want, rtol=tol, atol=tol)
@@ -117,10 +139,11 @@ def test_dispatch_env_default(monkeypatch):
     case = _pool_case(rng, num_pages=6, page=4, Hkv=2, G=2, D=8,
                       positions=[5, 9])
     q, pk, pv, tab, pos, _ = case
+    pk, pv = _stacked(rng, pk), _stacked(rng, pv)
     assert kops.kernel_mode() == "off"
-    off = kops.paged_attention(q, pk, pv, tab, pos)
+    off = kops.paged_attention(q, pk, pv, tab, pos, LAYER)
     monkeypatch.setattr(kops, "kernel_mode", lambda: "interpret")
-    interp = kops.paged_attention(q, pk, pv, tab, pos)
+    interp = kops.paged_attention(q, pk, pv, tab, pos, LAYER)
     np.testing.assert_allclose(np.asarray(off), np.asarray(interp),
                                rtol=2e-5, atol=2e-5)
 
@@ -129,9 +152,110 @@ def test_output_dtype_follows_query():
     rng = np.random.RandomState(2)
     q, pk, pv, tab, pos, _ = _pool_case(rng, num_pages=6, page=4, Hkv=2,
                                         G=2, D=8, positions=[5, 9])
-    out = kops.paged_attention(q.astype(jnp.bfloat16), pk, pv, tab, pos,
-                               mode="off")
+    out = kops.paged_attention(q.astype(jnp.bfloat16), pk[None], pv[None],
+                               tab, pos, 0, mode="off")
     assert out.dtype == jnp.bfloat16
+
+
+# ---------------------------------------------------------------------------
+# The stacked pool: a layer read in place equals the call on its own slice,
+# and a decode write into one layer leaves every other layer untouched
+# ---------------------------------------------------------------------------
+def _lane_pool(rng, lane, shape):
+    """(pool K, pool V, scale kwargs) of a lane: f32 values, or int8 codes
+    with positive per-(layer, page, head) scales."""
+    if lane == "f32":
+        return (jnp.asarray(rng.randn(*shape).astype(np.float32)),
+                jnp.asarray(rng.randn(*shape).astype(np.float32)), {})
+    codes = lambda: jnp.asarray(                        # noqa: E731
+        rng.randint(-127, 128, shape).astype(np.int8))
+    sshape = (*shape[:2], shape[3])
+    scales = lambda: jnp.asarray(                       # noqa: E731
+        rng.uniform(0.5, 1.5, sshape).astype(np.float32) / 127)
+    return codes(), codes(), {"k_scale": scales(), "v_scale": scales()}
+
+
+@pytest.mark.parametrize("mode", ["off", "interpret"])
+@pytest.mark.parametrize("lane", ["f32", "int8"])
+def test_stacked_layer_matches_its_own_slice(lane, mode):
+    """Layer 2 of a 3-layer pool, read in place by the stream lowering and
+    by the kernel, gives the same bits as the same call on that layer's
+    own (P, page, Hkv, D) slice."""
+    rng = np.random.RandomState(4)
+    n, layer, P_, page, Hkv, G, D = 3, 2, 9, 4, 2, 2, 8
+    pk, pv, sc = _lane_pool(rng, lane, (n, P_, page, Hkv, D))
+    table = jnp.asarray([[1, 2, 3, 0], [4, 5, 0, 0], [0, 0, 0, 0]],
+                        jnp.int32)
+    pos = jnp.asarray([13, 5, -1], jnp.int32)
+    q = jnp.asarray(rng.randn(3, Hkv * G, D).astype(np.float32))
+    got = kops.paged_attention(q, pk, pv, table, pos, layer, mode=mode, **sc)
+    own = kops.paged_attention(
+        q, pk[layer][None], pv[layer][None], table, pos, 0, mode=mode,
+        **{k: v[layer][None] for k, v in sc.items()})
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(own))
+    assert np.asarray(got)[:2].any() and not np.asarray(got)[2].any()
+
+
+@pytest.mark.parametrize("lane", ["f32", "int8"])
+def test_decode_write_leaves_other_layers_untouched(lane):
+    """One paged decode step of an attention block at layer g writes each
+    live slot's row at (g, page, offset) of the stacked leaves: every
+    other layer (and, at layer g, every page no slot writes) keeps its
+    bits, scales included."""
+    cfg = get_smoke_config("tinyllama-1.1b").replace(dtype="float32")
+    a = cfg.attention
+    n, P_, page, g = 3, 7, 4, 1
+    rng = np.random.RandomState(5)
+    pk, pv, sc = _lane_pool(rng, lane,
+                            (n, P_, page, a.num_kv_heads, a.head_dim))
+    cache = {"k": pk, "v": pv, **sc}
+    before = {k: np.asarray(v) for k, v in cache.items()}
+    table = jnp.asarray([[1, 2], [3, 4], [0, 0]], jnp.int32)
+    pos = jnp.asarray([5, 2, -1], jnp.int32)      # pages 2 and 3 written
+    params = attn_lib.init_attention(jax.random.PRNGKey(0), cfg,
+                                     cfg.d_model, cfg.compression)
+    x = jnp.asarray(rng.randn(3, 1, cfg.d_model).astype(np.float32))
+    _, new = attn_lib.attention_block(
+        params, x, cfg=cfg, cache=cache, cache_pos=pos, mode="serve",
+        block_table=table, layer=jnp.int32(g))
+    written = [2, 3, 0]                          # trash page 0: idle slot
+    for key, old in before.items():
+        got = np.asarray(new[key])
+        others = [i for i in range(n) if i != g]
+        np.testing.assert_array_equal(got[others], old[others])
+        keep = [p for p in range(P_) if p not in written]
+        np.testing.assert_array_equal(got[g, keep], old[g, keep])
+    # the live slots' rows hold their new values
+    assert (np.asarray(new["k"])[g, 2, 1] != before["k"][g, 2, 1]).any()
+    assert (np.asarray(new["k"])[g, 3, 2] != before["k"][g, 3, 2]).any()
+
+
+def test_unrolled_paged_decode_matches_scan():
+    """The unrolled layer loop serves paged decode from the same carried
+    pool, each group at its static index: logits and pool match the
+    scanned step's."""
+    cfg = get_smoke_config("llama4-maverick-400b-a17b").replace(
+        dtype="float32")
+    model = build_model(cfg)
+    params = model.init(jax.random.PRNGKey(0))
+    rng = np.random.RandomState(6)
+    pool = jax.tree.map(
+        lambda a: jnp.asarray(rng.randn(*a.shape).astype(np.float32)),
+        kvc.build_pool(cfg, num_pages=7, page_size=4))
+    table = jnp.asarray([[1, 2], [3, 4], [0, 0]], jnp.int32)
+    pos = jnp.asarray([5, 2, -1], jnp.int32)
+    tok = jnp.asarray([[3], [7], [0]], jnp.int32)
+    outs = []
+    for unroll in (False, True):
+        m = build_model(cfg.replace(unroll_scan=unroll))
+        outs.append(jax.jit(lambda p, t, c: m.decode_step(
+            p, t, c, pos, block_table=table))(params, tok, pool))
+    (lg_s, pool_s), (lg_u, pool_u) = outs
+    np.testing.assert_allclose(np.asarray(lg_u), np.asarray(lg_s),
+                               rtol=1e-5, atol=1e-5)
+    for a, b in zip(jax.tree.leaves(pool_u), jax.tree.leaves(pool_s)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=1e-6, atol=1e-6)
 
 
 # ---------------------------------------------------------------------------

@@ -69,9 +69,9 @@ def test_aot_compile_raises_on_compile_error():
     to an uncosted jit wrapper: here the Pallas TPU kernel, lowered without
     interpret mode, on the CPU."""
     from repro.kernels.paged_attention import paged_attention_kernel
-    pool = jnp.ones((2, 4, 1, 8))
+    pool = jnp.ones((1, 2, 4, 1, 8))
     args = (jnp.ones((1, 2, 8)), pool, pool, jnp.ones((1, 1), jnp.int32),
-            jnp.zeros((1,), jnp.int32))
+            jnp.zeros((1,), jnp.int32), jnp.int32(0))
     prof = Profiler(Registry(), hardware=HOST_CPU)
     with pytest.raises(ValueError, match="interpret"):
         aot_compile(jax.jit(paged_attention_kernel), args, prof, "pa")

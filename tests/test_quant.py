@@ -81,20 +81,24 @@ def test_int4_pack_unpack_exact_inverse():
 def test_page_scatter_invariants():
     """Scales only grow, always cover the page's live content, written
     values round-trip within the codec bound (+ one half-step per scale
-    growth for earlier residents), and untouched pages stay untouched."""
+    growth for earlier residents), and untouched pages stay untouched —
+    the other layers of the stacked pool too."""
     rng = np.random.RandomState(0)
     page, H, D = 4, 2, 3
-    pool = jnp.zeros((5, page, H, D), jnp.int8)
-    scales = jnp.zeros((5, H), jnp.float32)
+    n, g = 3, 1                                  # writes go to layer g
+    stack = jnp.zeros((n, 5, page, H, D), jnp.int8)
+    stack_sc = jnp.zeros((n, 5, H), jnp.float32)
+    pool, scales = stack[g], stack_sc[g]         # layer g's view, for reads
     pid = jnp.asarray([1, 3], jnp.int32)
     written = np.zeros((2, page, H, D), np.float32)
     grows = np.zeros((2, page, H), np.int32)     # growth events AFTER write
     prev = np.zeros((2, H), np.float32)
     for i in range(page):
         x = rng.randn(2, H, D).astype(np.float32) * (i + 1)   # forces growth
-        pool, scales = qc.page_scatter(pool, scales, pid,
-                                       jnp.asarray([i, i], jnp.int32),
-                                       jnp.asarray(x))
+        stack, stack_sc = qc.page_scatter(stack, stack_sc, g, pid,
+                                          jnp.asarray([i, i], jnp.int32),
+                                          jnp.asarray(x))
+        pool, scales = stack[g], stack_sc[g]
         s = np.asarray(scales)[np.asarray(pid)]               # (2, H)
         assert (s >= prev - 1e-12).all(), "scale shrank"
         grows[:, :i] += (s > prev + 1e-12)[:, None, :]
@@ -115,9 +119,10 @@ def test_page_scatter_invariants():
     # scales and every other resident row bit-unchanged
     before_pool, before_scales = np.asarray(pool), np.asarray(scales)
     small = rng.randn(2, H, D).astype(np.float32) * 1e-3
-    pool, scales = qc.page_scatter(pool, scales, pid,
-                                   jnp.asarray([1, 2], jnp.int32),
-                                   jnp.asarray(small))
+    stack, stack_sc = qc.page_scatter(stack, stack_sc, g, pid,
+                                      jnp.asarray([1, 2], jnp.int32),
+                                      jnp.asarray(small))
+    pool, scales = stack[g], stack_sc[g]
     assert (np.asarray(scales) == before_scales).all()
     after = np.asarray(pool)
     rows = np.ones((5, page), bool)
@@ -126,6 +131,10 @@ def test_page_scatter_invariants():
     want = np.clip(np.round(small / prev[:, :, None]), -127, 127)
     got = after[np.asarray(pid), np.asarray([1, 2])]
     assert (got == want).all()
+    # the other layers of the stack were never written
+    others = [i for i in range(n) if i != g]
+    assert not np.asarray(stack)[others].any()
+    assert not np.asarray(stack_sc)[others].any()
 
 
 def test_pack_prefill_quantizes_per_page_per_head():
@@ -267,15 +276,17 @@ def test_quantized_paged_attention_modes_agree():
                         jnp.int32)
     pos = jnp.asarray([13, 5, -1], jnp.int32)
     q = jnp.asarray(rng.randn(3, Hkv * G, D).astype(np.float32))
-    kw = dict(k_scale=sk, v_scale=sv)
-    off = kops.paged_attention(q, qk, qv, table, pos, mode="off", **kw)
-    interp = kops.paged_attention(q, qk, qv, table, pos, mode="interpret",
-                                  **kw)
+    kw = dict(k_scale=sk[None], v_scale=sv[None])       # a 1-layer stack
+    off = kops.paged_attention(q, qk[None], qv[None], table, pos, 0,
+                               mode="off", **kw)
+    interp = kops.paged_attention(q, qk[None], qv[None], table, pos, 0,
+                                  mode="interpret", **kw)
     # both lanes read the SAME dequantized values: the f32 lane run on the
     # explicitly dequantized pool is the bit-level reference for 'off'
     dqk = qc.dequantize(qk, sk[:, None, :, None])
     dqv = qc.dequantize(qv, sv[:, None, :, None])
-    ref = kops.paged_attention(q, dqk, dqv, table, pos, mode="off")
+    ref = kops.paged_attention(q, dqk[None], dqv[None], table, pos, 0,
+                               mode="off")
     np.testing.assert_array_equal(np.asarray(off), np.asarray(ref))
     np.testing.assert_allclose(np.asarray(interp), np.asarray(ref),
                                rtol=2e-6, atol=2e-6)

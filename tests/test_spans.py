@@ -220,12 +220,13 @@ def test_pallas_calls_carry_their_names():
     f32 = lambda *s: np.ones(s, np.float32)      # noqa: E731
     table = np.array([[1, 2], [3, 4]], np.int32)
     pos = np.array([10, 3], np.int32)
-    pool = f32(5, 8, 2, 32)
+    pool = f32(2, 5, 8, 2, 32)
     cases = {
         "paged_attention": (lambda *a: kops.paged_attention(
-            *a, mode="interpret"), (f32(2, 4, 32), pool, pool, table, pos)),
+            *a, 1, mode="interpret"), (f32(2, 4, 32), pool, pool, table,
+                                       pos)),
         "paged_gather": (lambda p, t: kops.paged_gather(
-            p, t, mode="interpret"), (pool, table)),
+            p, t, 1, mode="interpret"), (pool, table)),
         "flash_attention": (lambda *a: flash_attention.flash_attention(
             *a, interpret=True), (f32(1, 2, 8, 32), f32(1, 1, 8, 32),
                                   f32(1, 1, 8, 32))),
