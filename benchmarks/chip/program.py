@@ -1,39 +1,34 @@
 """The benchmark's one door into the system under test: build its
 configuration from a configuration file, build the continuous engine the
-launcher serves with, and count its compiles.  Nothing else of the
-benchmark imports the program."""
+launcher serves with, count its compiles, read its metrics over a window,
+and put a trace's device time under its named scopes.  Nothing else of
+the benchmark imports the program."""
 from __future__ import annotations
 
 import os
 
 import jax
 
+import arch
+
 DTYPES = {"float32": "f32", "bfloat16": "bf16", "int8": "int8"}
 
 
 def arch_config(conf: dict):
-    """The program's ``ArchConfig`` for a configuration file."""
+    """The program's ``ArchConfig`` for a configuration file, from the
+    fields that the module of its architecture gives (``arch.py``)."""
     from repro.configs.base import (ArchConfig, AttentionConfig,
-                                    CompressionConfig)
+                                    CompressionConfig, MoEConfig)
     served = conf["served"]
     if served["param_dtype"] != "float32":
         raise ValueError("the program keeps its parameters in float32")
-    return ArchConfig(
-        name=conf["name"], family="dense",
-        num_layers=conf["num_hidden_layers"], d_model=conf["hidden_size"],
-        d_ff=conf["intermediate_size"], vocab_size=conf["vocab_size"],
-        attention=AttentionConfig(
-            num_heads=conf["num_attention_heads"],
-            num_kv_heads=conf["num_key_value_heads"],
-            head_dim=conf["head_dim"], rope_theta=float(conf["rope_theta"]),
-            qk_norm=bool(conf.get("qk_norm")),
-            qkv_bias=bool(conf.get("qkv_bias"))),
-        compression=CompressionConfig(
-            enabled=True, block_attn=conf["circulant_block"]["attn"],
-            block_ffn=conf["circulant_block"]["ffn"],
-            block_embed=conf["circulant_block"]["head"]),
-        tie_embeddings=bool(conf["tie_word_embeddings"]),
-        dtype=served["activation_dtype"], param_dtype="float32")
+    fields = dict(arch.module(conf).program_fields(conf))
+    for key, cls in (("attention", AttentionConfig), ("moe", MoEConfig),
+                     ("compression", CompressionConfig)):
+        if key in fields:
+            fields[key] = cls(**fields[key])
+    return ArchConfig(**fields, dtype=served["activation_dtype"],
+                      param_dtype="float32")
 
 
 def build_engine(conf: dict, cell: dict, params, max_seq: int, device):
@@ -96,3 +91,38 @@ class CompileClock:
     def _event(self, event, **_):
         if event == "/jax/compilation_cache/cache_hits":
             self.cache_hits += 1
+
+
+# ---------------------------------------------------------------------------
+# The program's metrics over a window, and its device time by scope
+# ---------------------------------------------------------------------------
+def metric_marks(engine) -> tuple:
+    """Where each histogram of the engine's registry stands, and its
+    counters' values: hand them to ``metrics_since``."""
+    reg = engine.obs.registry
+    return ({name: m.mark() for name, m in reg.items()
+             if hasattr(m, "values_since")},
+            reg.snapshot())
+
+
+def metrics_since(engine, marks) -> tuple:
+    """(every histogram's values observed since ``marks``, every
+    counter's increase since then), by the registry's flat names."""
+    reg = engine.obs.registry
+    at, before = marks
+    hists = {name: m.values_since(at.get(name, 0))
+             for name, m in reg.items() if hasattr(m, "values_since")}
+    return hists, reg.delta(reg.snapshot(), before)
+
+
+def op_scopes(engine) -> list:
+    """The op->scope map of every program the engine compiled; take it
+    before the engine is freed."""
+    return list(engine.op_scopes().values())
+
+
+def scope_seconds(xplane_path: str, scopes: list) -> dict:
+    """Leaf-op device seconds of a trace's programs by named scope, per
+    program (``repro.obs.scopes.device_seconds``)."""
+    from repro.obs.scopes import device_seconds_file
+    return device_seconds_file(xplane_path, scopes)

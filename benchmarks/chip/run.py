@@ -6,8 +6,9 @@
 The cell (an entry of ``workloads`` in ``BENCHMARK.json``) names a
 configuration (``configs/<name>.json``) and a traffic mix
 (``traffic/<name>.json``); its rate, slots and pool are in
-``cells/<workload>.json``; each per-layer metric is read by
-``metrics/<metric>.py``.  A run:
+``cells/<workload>.json``; the configuration's architecture is served by
+its module under ``models/`` (``arch.py``); each per-layer metric is read
+by ``metrics/<metric>.py``.  A run:
 
 1. makes the weights from the seed on the device, builds the program's
    continuous engine and warms every prefill bucket the mix can reach and
@@ -51,6 +52,7 @@ CACHE_DIR = ROOT / ".jax_cache"
 
 import numpy as np  # noqa: E402
 
+import arch  # noqa: E402
 import traffic as traffic_lib  # noqa: E402
 from stats import percentile  # noqa: E402
 import work  # noqa: E402
@@ -91,10 +93,16 @@ def load_cell(name: str, root: pathlib.Path = ROOT) -> Cell:
     w = cells[name]
     confs = {c["name"]: c for c in bench["configs"]}
     conf = _load_json(root / confs[w["config"]]["file"])
+    arch.module(conf)
     mix = _load_json(HERE / "traffic" / f"{w['traffic']}.json")
     params = _load_json(HERE / "cells" / f"{name}.json")
-    return Cell(name, int(w["chips"]), conf, mix, params,
+    cell = Cell(name, int(w["chips"]), conf, mix, params,
                 bench["end_to_end"], bench["per_layer"])
+    longest = conf.get("max_position_embeddings")
+    if longest is not None and cell.max_seq > longest:
+        raise ValueError(f"{name}: sequences of {cell.max_seq} positions, "
+                         f"the configuration has {longest}")
+    return cell
 
 
 def load_metric(name: str):
@@ -137,18 +145,30 @@ class Served:
 
 
 @dataclasses.dataclass
+class Registry:
+    """The program's metrics over the window, by their flat names
+    (``name{label=value}``): each histogram's values observed in it and
+    each counter's increase."""
+    histograms: Dict[str, List[float]]
+    counters: Dict[str, float]
+
+
+@dataclasses.dataclass
 class Context:
     cell: Cell
     seconds: float
     requests: List[Served]
-    histograms: Dict[str, List[float]]     # program histograms, window only
-    shapes: work.Shapes
+    registry: Registry
     peaks: object = None
     trace: object = None                   # trace_reduce.Reduction
+    # leaf-op device seconds by named scope, per traced program
+    # (``repro.obs.scopes.device_seconds``: {"jit_decode_loop": {"runs",
+    # "leaf_s", "top", "under", "unscoped_ops"}, ...})
+    scope_s: Optional[Dict[str, dict]] = None
     traced_work: Optional[Dict[str, float]] = None
 
     def hist(self, name: str) -> List[float]:
-        return self.histograms.get(name, [])
+        return self.registry.histograms.get(name, [])
 
     @property
     def traced_from(self) -> float:
@@ -271,32 +291,36 @@ def _start_trace(trace_dir: str) -> None:
     jax.profiler.start_trace(trace_dir, profiler_options=opts)
 
 
-def traced_work(engine, shapes: work.Shapes, interval) -> Dict[str, float]:
+def traced_work(engine, conf: dict, interval) -> Dict[str, float]:
     """Operations and bytes of the prefills and decode steps that ran in
     the traced interval, rebuilt from each request's own timeline: a
     prefill ran there if its request was admitted and got its first token
     inside it; a decode chunk did if it ended inside it (the interval
-    opens and closes between engine steps)."""
+    opens and closes between engine steps).  The slots of one decode
+    dispatch share its end; its steps are the most tokens a slot took."""
     lo, hi = interval
     out = {"prefill_flops": 0.0, "prefills": 0, "decode_flops": 0.0,
            "decode_tokens": 0, "kv_bytes": 0.0, "kernel_flops": 0.0}
+    steps: Dict[float, int] = {}
     for tr in engine.obs.traces.completed:
         if tr.first_token_s is None:
             continue
         S = tr.prompt_len
         if lo <= tr.admit_s and tr.first_token_s <= hi:
-            out["prefill_flops"] += work.prefill_flops(shapes, S)
+            out["prefill_flops"] += work.prefill_flops(conf, S)
             out["prefills"] += 1
         emitted = 1
         for t_end, n in tr.chunks:
             if lo <= t_end <= hi:
                 for j in range(n):
                     ctx = S + emitted + j       # positions the query attends
-                    out["decode_flops"] += work.decode_flops(shapes, ctx)
-                    out["kv_bytes"] += work.kv_read_bytes(shapes, ctx)
-                    out["kernel_flops"] += work.kernel_flops(shapes, ctx)
+                    out["decode_flops"] += work.decode_flops(conf, ctx)
+                    out["kv_bytes"] += work.kv_read_bytes(conf, ctx)
+                    out["kernel_flops"] += work.kernel_flops(conf, ctx)
                 out["decode_tokens"] += n
+                steps[t_end] = max(steps.get(t_end, 0), n)
             emitted += n
+    out["decode_steps"] = sum(steps.values())
     return out
 
 
@@ -406,16 +430,13 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool,
                  if require_tpu else None)
     clock = program.CompileClock()
     device = devices[0]
-    shapes = work.Shapes.of(cell.conf)
 
     params, engine, c_before, times = set_up(cell, seed, device, clock,
                                              fault)
     arrivals = traffic_lib.generate(cell.mix, cell.params["rate_per_s"],
                                     seconds, seed,
                                     cell.conf["vocab_size"])
-    hist_names = ("engine.prefill_dispatch_s", "engine.decode_chunk_s")
-    reg = engine.obs.registry
-    hist_at = {n: len(reg.histogram(n)._values) for n in hist_names}
+    marks = program.metric_marks(engine)
     compiles_setup = clock.compiles
     setup_s = time.perf_counter() - T_PROCESS
     log(f"set-up {setup_s:.2f}s: weights {times['weights']:.2f}s, engine "
@@ -431,24 +452,26 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool,
             served, interval = serve_window(engine, program, arrivals,
                                             seconds, trace_dir)
         compiles_window = clock.compiles - compiles_setup
-        histograms = {n: list(reg.histogram(n)._values[hist_at[n]:])
-                      for n in hist_names}
+        registry = Registry(*program.metrics_since(engine, marks))
         reduction = None
+        scope_s = None
         tw = None
         if trace:
             import trace_reduce
-            reduction = trace_reduce.reduce_file(
-                trace_reduce.find_xplane(trace_dir))
+            xplane = trace_reduce.find_xplane(trace_dir)
+            reduction = trace_reduce.reduce_file(xplane)
+            scope_s = program.scope_seconds(xplane,
+                                            program.op_scopes(engine))
             if interval is None:
                 raise RuntimeError("the window ended before its traced part")
-            tw = traced_work(engine, shapes, interval)
+            tw = traced_work(engine, cell.conf, interval)
         info = device_info(devices, cell.chips, reduction)
     finally:
         if trace_dir is not None:
             shutil.rmtree(trace_dir, ignore_errors=True)
     failed = sum(1 for s in served if not s.finished)
-    ctx = Context(cell, seconds, served, histograms, shapes, peaks,
-                  reduction, tw)
+    ctx = Context(cell, seconds, served, registry, peaks, reduction,
+                  scope_s, tw)
     log(f"window: {len(served)} requests, {failed} not finished, compiles "
         f"in window {compiles_window}")
 

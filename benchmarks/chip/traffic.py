@@ -11,14 +11,14 @@ gives the mean rate.  Keys of a mix:
 
 The *shape* of the work is drawn once from ``shape_seed``: how many
 requests, their lengths, and the arrival times.  The run's
-``--seed`` only reorders it (the gaps between Poisson arrivals, and which
-request comes when) and draws the token ids, so every seed serves the same
-amount of work and the runs of a cell differ by ordering alone.  The
-reordering stays inside blocks of ``REORDER_BLOCK_S`` seconds of the
-canonical timeline: every block of every seed holds the same requests and
-ends at the same arrival, so the work offered up to any block's end, and
+``--seed`` only reorders it (which request arrives at which of the
+shape's arrival times) and draws the token ids, so every seed serves the
+same amount of work at the same instants and the runs of a cell differ by
+ordering alone.  The reordering stays inside blocks of
+``REORDER_BLOCK_S`` seconds of the timeline: every block of every seed
+holds the same requests, so the work offered up to any block's end, and
 the work still in flight when the window closes, is the same for every
-seed.
+seed, and no request moves by more than a block.
 """
 from __future__ import annotations
 
@@ -28,7 +28,7 @@ from typing import List
 
 import numpy as np
 
-REORDER_BLOCK_S = 5.0
+REORDER_BLOCK_S = 1.0
 
 
 @dataclasses.dataclass(frozen=True)
@@ -71,12 +71,8 @@ def generate(mix: dict, rate_per_s: float, seconds: float, seed: int,
     rng = np.random.default_rng(seed)
     block = np.floor(sh["times"] / REORDER_BLOCK_S)
     order = _shuffle_within(rng, block)
-    gaps = np.diff(np.concatenate([[0.0], sh["times"]]))
-    # reorder the gaps inside each block too: a block's gaps sum to the
-    # same last arrival whatever their order
-    times = np.cumsum(gaps[_shuffle_within(rng, block)])
     out = []
-    for i, (j, t) in enumerate(zip(order, times)):
+    for i, (j, t) in enumerate(zip(order, sh["times"])):
         prompt = rng.integers(1, vocab_size, size=int(sh["prompts"][j]),
                               dtype=np.int64)
         out.append(Arrival(i, float(t), prompt.astype(np.int32),

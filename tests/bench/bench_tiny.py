@@ -3,8 +3,10 @@ keys at a size a test run can hold."""
 
 
 def tiny_conf(qk_norm: bool, qkv_bias: bool) -> dict:
+    """Qwen3's mechanism with ``qk_norm``, Qwen2's with ``qkv_bias``."""
     return {
-        "name": "tiny", "hidden_size": 256, "intermediate_size": 512,
+        "name": "tiny", "model_type": "qwen3" if qk_norm else "qwen2",
+        "hidden_size": 256, "intermediate_size": 512,
         "num_hidden_layers": 2, "num_attention_heads": 4,
         "num_key_value_heads": 2, "head_dim": 64, "vocab_size": 1024,
         "rope_theta": 1000000.0, "rms_norm_eps": 1e-06,

@@ -124,3 +124,39 @@ def test_small_trace_reduction_is_unchanged():
         "bench.step", "PjitFunction(prefill_pack)"]
     assert [s for _, s in r.idle_gaps[:5]] == pytest.approx(
         [0.051711289, 0.001013113, 0.00098307, 0.000815398, 0.000602047])
+
+
+@pytest.mark.parametrize("name,program,scope", [
+    ("decode.lm_head_s_per_step", "jit_decode_loop", "lm_head"),
+    ("decode.spectral_s_per_step", "jit_decode_loop", "spectral")])
+def test_scope_seconds_per_decode_step(by_scope, name, program, scope):
+    """The readers of the decode programs' named scopes, on the recorded
+    trace: the scope's leaf seconds over the traced decode steps; silent
+    without a trace, a step, or the scope."""
+    import types
+
+    import run
+    metric = run.load_metric(name)
+    ctx = types.SimpleNamespace(scope_s=by_scope,
+                                traced_work={"decode_steps": 40})
+    assert metric.read(ctx) == by_scope[program]["under"][scope] / 40
+    assert metric.read(ctx) > 0
+    ctx.traced_work = {"decode_steps": 0}
+    assert metric.read(ctx) is None
+    assert metric.read(types.SimpleNamespace(
+        scope_s=None, traced_work={"decode_steps": 40})) is None
+    bare = {program: dict(by_scope[program], under={})}
+    assert metric.read(types.SimpleNamespace(
+        scope_s=bare, traced_work={"decode_steps": 40})) is None
+
+
+def test_prefill_head_share(by_scope):
+    import types
+
+    import run
+    metric = run.load_metric("prefill.lm_head_share")
+    pre = by_scope["jit_prefill_pack"]
+    share = metric.read(types.SimpleNamespace(scope_s=by_scope))
+    assert share == 100.0 * pre["under"]["lm_head"] / pre["leaf_s"]
+    assert 0 < share < 100
+    assert metric.read(types.SimpleNamespace(scope_s={})) is None

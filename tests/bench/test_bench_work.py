@@ -4,14 +4,22 @@ import math
 import pytest
 
 import work
+from models import qwen
+
+
+def conf(**kw):
+    """qwen3-4b's shapes, with ``kw`` changed."""
+    base = dict(model_type="qwen3", num_hidden_layers=36, hidden_size=2560,
+                intermediate_size=9728, num_attention_heads=32,
+                num_key_value_heads=8, head_dim=128, vocab_size=151936,
+                circulant_block={"attn": 128, "ffn": 128, "head": 0},
+                served={"kv_pool_dtype": "float32"})
+    base.update(kw)
+    return base
 
 
 def shapes(**kw):
-    base = dict(layers=36, d_model=2560, d_ff=9728, heads=32, kv_heads=8,
-                head_dim=128, vocab=151936, block_attn=128, block_ffn=128,
-                kv_bytes=4)
-    base.update(kw)
-    return work.Shapes(**base)
+    return qwen.Shapes.of(conf(**kw))
 
 
 def test_fft_count_at_block_128():
@@ -33,38 +41,40 @@ def test_layer_projections_sum_the_seven():
     p = work.projection_flops
     want = (p(2560, 4096, 128) + 2 * p(2560, 1024, 128) + p(4096, 2560, 128)
             + 2 * p(2560, 9728, 128) + p(9728, 2560, 128))
-    assert work.layer_projection_flops(s) == want
+    assert qwen.layer_projection_flops(s) == want
 
 
 def test_head_flops():
-    assert work.head_flops(shapes()) == 2.0 * 2560 * 151936
+    assert qwen.head_flops(shapes()) == 2.0 * 2560 * 151936
 
 
 def test_prefill_counts_the_head_once_and_causal_attention():
-    s = shapes(layers=1)
+    c = conf(num_hidden_layers=1)
+    s = qwen.Shapes.of(c)
     n = 10
     attn = 4.0 * 32 * 128 * sum(range(1, n + 1))
-    want = n * work.layer_projection_flops(s) + attn + work.head_flops(s)
-    assert work.prefill_flops(s, n) == pytest.approx(want)
+    want = n * qwen.layer_projection_flops(s) + attn + qwen.head_flops(s)
+    assert work.prefill_flops(c, n) == pytest.approx(want)
 
 
 def test_kv_bytes_for_a_known_schedule():
     # two slots decode 3 steps from prompts of 5 and 9 tokens: the queries
     # attend 6, 7, 8 and 10, 11, 12 positions
-    s = shapes(layers=2, kv_heads=2, head_dim=4, kv_bytes=4)
+    c = conf(num_hidden_layers=2, num_key_value_heads=2, head_dim=4)
     contexts = [6, 7, 8, 10, 11, 12]
     per_pos = 2 * 2 * 2 * 4 * 4          # K and V, layers, heads, dim, bytes
-    assert sum(work.kv_read_bytes(s, c) for c in contexts) == \
+    assert sum(work.kv_read_bytes(c, x) for x in contexts) == \
         per_pos * sum(contexts)
 
 
 def test_decode_flops_split():
-    s = shapes()
-    c = 1000
-    assert work.decode_flops(s, c) == pytest.approx(
-        s.layers * work.layer_projection_flops(s) + work.kernel_flops(s, c)
-        + work.head_flops(s))
-    assert work.kernel_flops(s, c) == 36 * 4.0 * 32 * 128 * c
+    c = conf()
+    s = qwen.Shapes.of(c)
+    x = 1000
+    assert work.decode_flops(c, x) == pytest.approx(
+        s.layers * qwen.layer_projection_flops(s) + work.kernel_flops(c, x)
+        + qwen.head_flops(s))
+    assert work.kernel_flops(c, x) == 36 * 4.0 * 32 * 128 * x
 
 
 def test_shapes_from_config_files():
@@ -74,6 +84,7 @@ def test_shapes_from_config_files():
     path = os.path.join(here, "..", "..", "benchmarks", "chip", "configs",
                         "qwen2.5-3b.json")
     with open(path) as f:
-        s = work.Shapes.of(json.load(f))
+        c = json.load(f)
+    s = qwen.Shapes.of(c)
     assert (s.heads, s.kv_heads, s.head_dim, s.kv_bytes) == (16, 2, 128, 4)
-    assert math.isclose(work.kv_read_bytes(s, 1) / 36, 2 * 2 * 128 * 4)
+    assert math.isclose(work.kv_read_bytes(c, 1) / 36, 2 * 2 * 128 * 4)
